@@ -28,16 +28,13 @@ import json
 import signal
 import sys
 import threading
+import tomllib
 import urllib.error
 import urllib.request
 from typing import Any, Dict, List, Optional
 
 
 def _load_fleet(path: str) -> Dict[str, int]:
-    try:
-        import tomllib  # Python >= 3.11
-    except ModuleNotFoundError:  # pragma: no cover - 3.10 path
-        import tomli as tomllib
     with open(path, "rb") as f:
         d = tomllib.load(f)
     pools = d.get("pools", d)  # accept both [pools.X] and top-level tables

@@ -25,6 +25,7 @@ import logging
 import os
 import threading
 import time
+import tomllib
 from typing import Any, Dict, List, Optional
 
 from repro.observe import EventLog
@@ -45,10 +46,6 @@ logger = logging.getLogger("repro.control.plane")
 
 
 def _load_toml_text(text: str) -> Dict[str, Any]:
-    try:
-        import tomllib  # Python >= 3.11
-    except ModuleNotFoundError:  # pragma: no cover - 3.10 path
-        import tomli as tomllib
     return tomllib.loads(text)
 
 

@@ -43,6 +43,7 @@ import argparse
 import importlib
 import json
 import sys
+import tomllib
 from typing import Any, Dict, List, Mapping, Optional
 
 from .executors import PoolSpec
@@ -541,7 +542,7 @@ def spec_from_dict(d: Mapping[str, Any]) -> Any:
 
 
 # --------------------------------------------------------------------------
-# TOML (write: minimal emitter for the spec subset; read: tomllib/tomli)
+# TOML (write: minimal emitter for the spec subset; read: tomllib)
 # --------------------------------------------------------------------------
 
 
@@ -601,10 +602,6 @@ def dumps_toml(d: Mapping[str, Any]) -> str:
 
 
 def _load_toml(path: str) -> Dict[str, Any]:
-    try:
-        import tomllib  # Python >= 3.11
-    except ModuleNotFoundError:  # pragma: no cover - 3.10 path
-        import tomli as tomllib
     with open(path, "rb") as f:
         return tomllib.load(f)
 

@@ -3,13 +3,17 @@
 Decode attention is *memory-bound*: the whole KV cache (up to 32k x
 kv_heads x 128 per sequence here) streams through VMEM once per step
 while compute is a rank-1 product. The kernel therefore tiles the cache
-sequence dimension — grid = (B * H, S / block_k), sequential over the
-cache — and keeps the online-softmax state for the single query row in
-VMEM scratch. block_k = 1024 x d=128 x bf16 = 256 kB per kv operand,
-sized so double-buffered HBM->VMEM streams saturate bandwidth.
+sequence dimension — grid = (B * KVH, S / block_k), sequential over the
+cache — and keeps the online-softmax state for the query rows in VMEM
+scratch. block_k = 1024 x d=128 x bf16 = 256 kB per kv operand, sized so
+double-buffered HBM->VMEM streams saturate bandwidth.
 
-GQA is folded into the index maps (kv head = q head // group), so the
-cache is read once per kv head group rather than once per q head.
+GQA is folded into the layout: q is viewed as (B * KVH, group, d), so one
+program instance attends all ``group`` q heads that share a kv head and
+the cache is read once per kv head. Every block's last two dims are either
+whole array dims (group, d) or (block_k, d) with block_k a multiple of 8,
+as the TPU tiling rule requires. Per-sequence lengths ride in SMEM through
+scalar prefetch.
 """
 
 from __future__ import annotations
@@ -26,7 +30,7 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    len_ref,                      # (1, 1) int32 in SMEM-ish block
+    len_ref,                      # (B,) int32, scalar-prefetched into SMEM
     q_ref, k_ref, v_ref,          # VMEM blocks
     o_ref,
     m_ref, l_ref, acc_ref,        # scratch
@@ -34,7 +38,9 @@ def _decode_kernel(
     sm_scale: float,
     block_k: int,
     window: int,
+    kv_heads: int,
 ):
+    bh = pl.program_id(0)
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -43,37 +49,37 @@ def _decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)          # (1, d)
+    q = q_ref[0].astype(jnp.float32)            # (group, d)
     k = k_ref[0].astype(jnp.float32)            # (bk, d)
     v = v_ref[0].astype(jnp.float32)            # (bk, d)
-    length = len_ref[0, 0]
+    length = len_ref[bh // kv_heads]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale                                 # (1, bk)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    ) * sm_scale                                 # (group, bk)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = k_pos < length
     if window > 0:
         mask &= k_pos >= length - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[0, 0]
-    l_prev = l_ref[0, 0]
-    m_cur = jnp.maximum(m_prev, s.max())
+    m_prev = m_ref[...]                          # (group, 128), lanes equal
+    l_prev = l_ref[...]
+    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur)
+    p = jnp.exp(s - m_cur[:, :1])
     p = jnp.where(mask, p, 0.0)
-    l_cur = l_prev * alpha + p.sum()
+    l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    m_ref[...] = m_cur
 
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    m_ref[...] = jnp.full_like(m_ref, m_cur)
-    l_ref[...] = jnp.full_like(l_ref, l_cur)
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(
@@ -94,30 +100,33 @@ def decode_attention_pallas(
     assert s % block_k == 0, (s, block_k)
     scale = sm_scale if sm_scale is not None else d ** -0.5
 
-    qf = q.reshape(b * h, d)
+    qf = q.reshape(b * kvh, group, d)
     kf = k.reshape(b * kvh, s, d)
     vf = v.reshape(b * kvh, s, d)
-    lens = jnp.broadcast_to(lengths[:, None], (b, h)).reshape(b * h, 1).astype(jnp.int32)
 
     kernel = functools.partial(
-        _decode_kernel, sm_scale=scale, block_k=block_k, window=window or 0
+        _decode_kernel, sm_scale=scale, block_k=block_k, window=window or 0,
+        kv_heads=kvh,
     )
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, s // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, ki: (bh, 0)),
-            pl.BlockSpec((1, d), lambda bh, ki: (bh, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, g=group: (bh // g, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, g=group: (bh // g, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda bh, ki: (bh, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * kvh, s // block_k),
+            in_specs=[
+                pl.BlockSpec((1, group, d), lambda bh, ki, lens: (bh, 0, 0)),
+                pl.BlockSpec((1, block_k, d), lambda bh, ki, lens: (bh, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda bh, ki, lens: (bh, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, group, d), lambda bh, ki, lens: (bh, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, 128), jnp.float32),
+                pltpu.VMEM((group, 128), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * kvh, group, d), q.dtype),
         interpret=interpret,
-    )(lens, qf, kf, vf)
+        name="decode_attention",
+    )(lengths.astype(jnp.int32), qf, kf, vf)
     return out.reshape(b, h, d)

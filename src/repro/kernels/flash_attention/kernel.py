@@ -158,5 +158,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, d), jnp.float32),     # acc
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out[:, :sq].reshape(b, h, sq, d)

@@ -53,5 +53,6 @@ def rmsnorm_pallas(
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xf, w.reshape(1, d))
     return out[:rows].reshape(orig_shape)

@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = ("pod", "data", "model") — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_devices: Optional[int] = None, model_axis: int = 2):
@@ -26,7 +27,8 @@ def make_test_mesh(n_devices: Optional[int] = None, model_axis: int = 2):
     n = n_devices or len(jax.devices())
     model_axis = min(model_axis, n)
     data_axis = n // model_axis
-    return jax.make_mesh((data_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((data_axis, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
@@ -41,6 +42,11 @@ from ..core import (
     stateful_task,
 )
 from ..core.thinker import ResourceCounter
+from .compile_cache import use_compile_cache
+
+# Consecutive failures of one chunk before the campaign gives up: the
+# server already retries lost workers, so repeats mean the step itself fails.
+MAX_CHUNK_FAILURES = 3
 
 
 def train_config(arch: str, scale: int = 1, seq: int = 64):
@@ -125,7 +131,8 @@ def save_checkpoint(registry: Optional[dict] = None) -> Dict[str, Any]:
 
 class TrainingThinker(BaseThinker):
     """Steers the campaign: chunk submission, loss tracking, checkpoint
-    cadence, plateau early-stop."""
+    cadence, plateau early-stop. A chunk that fails ``MAX_CHUNK_FAILURES``
+    times in a row ends the campaign with ``error`` set."""
 
     def __init__(self, queues, *, arch: str, scale: int, total_steps: int,
                  chunk: int, seq: int, batch: int, lr: float,
@@ -142,6 +149,8 @@ class TrainingThinker(BaseThinker):
         self.next_step = 0
         self.last_ckpt = 0
         self.preempted = False
+        self.chunk_failures = 0
+        self.error: Optional[str] = None
 
     def _submit_chunk(self):
         k = min(self.chunk, self.total_steps - self.next_step)
@@ -161,9 +170,17 @@ class TrainingThinker(BaseThinker):
         if result.method == "save_checkpoint":
             return
         if not result.success:
+            self.chunk_failures += 1
+            if self.chunk_failures >= MAX_CHUNK_FAILURES:
+                self.error = (f"chunk at step {self.next_step} failed "
+                              f"{self.chunk_failures} times: {result.failure_info}")
+                self.logger.error("%s; giving up", self.error)
+                self.done.set()
+                return
             self.logger.warning("chunk failed (%s); resubmitting", result.failure_info)
             self._submit_chunk()
             return
+        self.chunk_failures = 0
         out = result.value
         self.losses.extend(out["losses"])
         self.next_step = out["end_step"]
@@ -220,6 +237,7 @@ def run(arch: str = "gemma-2b", steps: int = 100, chunk: int = 10, scale: int = 
         "preempted": thinker.preempted,
         "workers_replaced": server.metrics.workers_replaced,
         "tasks_retried": server.metrics.tasks_retried,
+        "error": thinker.error,
     }
 
 
@@ -237,10 +255,13 @@ def main() -> None:
     ap.add_argument("--preempt-at", type=int, default=None,
                     help="inject a node failure at this step (tests recovery)")
     args = ap.parse_args()
+    use_compile_cache()
     report = run(arch=args.arch, steps=args.steps, chunk=args.chunk, scale=args.scale,
                  seq=args.seq, batch=args.batch, lr=args.lr, ckpt_dir=args.ckpt_dir,
                  ckpt_every=args.ckpt_every, preempt_at=args.preempt_at)
     print(json.dumps(report, indent=2))
+    if report["error"] or report["steps"] < args.steps:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -15,6 +15,7 @@ dry run, guaranteeing they never drift apart.
 
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -171,7 +172,14 @@ class ParamDef:
         if self.init == "ones":
             return jnp.ones(self.shape, dtype)
         scale = self.scale if self.init == "normal" else self.scale * 0.1
-        return (jax.random.normal(key, self.shape, jnp.float32) * scale).astype(dtype)
+        return _scaled_normal(key, self.shape, dtype, scale)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _scaled_normal(key: jax.Array, shape: Tuple[int, ...], dtype, scale: float) -> jnp.ndarray:
+    # Jitted so the float32 draw fuses into the cast: eagerly, a stacked
+    # 32-layer leaf at published width is a multi-GB float32 temporary.
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
 def _traverse(tree: Any, fn: Callable[[ParamDef, Tuple], Any], path: Tuple = ()) -> Any:

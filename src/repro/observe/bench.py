@@ -21,10 +21,12 @@ CLI (soft-fail annotation in CI; hard gates stay in the suites).
 
 from __future__ import annotations
 
+import importlib.metadata
 import json
 import os
 import platform
 import subprocess
+import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -51,26 +53,25 @@ def git_commit(cwd: Optional[str] = None) -> Optional[str]:
 
 def env_fingerprint() -> Dict[str, Any]:
     """What the numbers were measured on — enough to explain a diff that
-    is really an environment change."""
+    is really an environment change.
+
+    It never starts a JAX backend: a process that records must leave the
+    chip to the process that measures. The backend is named only when
+    this process has already started one."""
     fp: Dict[str, Any] = {
         "python": platform.python_version(),
         "platform": platform.platform(),
         "machine": platform.machine(),
         "cpu_count": os.cpu_count(),
+        "jax": importlib.metadata.version("jax"),
+        "numpy": importlib.metadata.version("numpy"),
     }
-    try:
+    if "jax" in sys.modules:
         import jax
+        from jax._src import xla_bridge
 
-        fp["jax"] = jax.__version__
-        fp["jax_backend"] = jax.default_backend()
-    except Exception:  # noqa: BLE001 - fingerprinting must never fail a suite
-        fp["jax"] = None
-    try:
-        import numpy
-
-        fp["numpy"] = numpy.__version__
-    except Exception:  # noqa: BLE001
-        fp["numpy"] = None
+        if xla_bridge.backends_are_initialized():
+            fp["jax_backend"] = jax.default_backend()
     return fp
 
 

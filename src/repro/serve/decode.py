@@ -13,7 +13,10 @@ from ..models.model_api import Model
 
 def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0) -> Callable:
     """Returns serve_step(params, cache, tokens, lengths, rng) ->
-    (next_tokens (B,1), logits (B,1,V), cache)."""
+    (next_tokens (B,1), logits_finite (), cache).
+
+    ``logits_finite`` is True when every logit of the step is finite; the
+    logits themselves stay on the device."""
 
     def serve_step(params, cache, tokens, lengths, rng):
         logits, cache = model.decode_step(params, cache, tokens, lengths)
@@ -21,7 +24,7 @@ def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0)
             nxt = jnp.argmax(logits[:, -1], axis=-1)
         else:
             nxt = jax.random.categorical(rng, logits[:, -1] / temperature, axis=-1)
-        return nxt[:, None].astype(jnp.int32), logits, cache
+        return nxt[:, None].astype(jnp.int32), jnp.isfinite(logits).all(), cache
 
     return serve_step
 

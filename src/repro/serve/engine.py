@@ -47,6 +47,7 @@ class EngineStats:
     requests_finished: int = 0
     requests_cancelled: int = 0
     batch_occupancy_sum: float = 0.0
+    nonfinite_steps: int = 0             # steps whose logits held a NaN/inf
 
     @property
     def mean_occupancy(self) -> float:
@@ -76,12 +77,24 @@ class ServingEngine:
 
         self._admit: "queue.Queue[Request]" = queue.Queue()
         self._slots: List[Optional[Request]] = [None] * n_slots
-        self._serve = jax.jit(make_serve_step(model))
+        # The cache is donated: each step writes its successor in place. Without
+        # it, every step dispatched ahead of the device holds a whole cache.
+        self._serve = jax.jit(make_serve_step(model), donate_argnums=(1,))
         self._cache = model.init_cache(n_slots, max_len)
         self._lengths = jnp.zeros((n_slots,), jnp.int32)
         self._tokens = jnp.zeros((n_slots, 1), jnp.int32)
         self._rng = jax.random.PRNGKey(0)
-        self._decode_jit = jax.jit(model.decode_step)
+
+    def compile(self) -> "jax.stages.Compiled":
+        """Compile the step for this engine's shapes ahead of serving.
+
+        Later steps run the returned executable, so no compilation falls
+        inside the serving window; its ``as_text()`` shows which kernels
+        the step runs."""
+        self._serve = self._serve.lower(
+            self.params, self._cache, self._tokens, self._lengths, self._rng
+        ).compile()
+        return self._serve
 
     # ----------------------------------------------------------------- admit
     def submit(self, req: Request) -> None:
@@ -130,12 +143,14 @@ class ServingEngine:
         if not active:
             return 0
         self._rng, sub = jax.random.split(self._rng)
-        nxt, logits, self._cache = self._serve(self.params, self._cache, self._tokens, self._lengths, sub)
-        nxt_np = np.asarray(nxt)
+        nxt, finite, self._cache = self._serve(self.params, self._cache, self._tokens, self._lengths, sub)
+        nxt_np, finite = jax.device_get((nxt, finite))
         self._tokens = nxt
         self._lengths = self._lengths + 1
 
         self.stats.steps += 1
+        if not finite:
+            self.stats.nonfinite_steps += 1
         self.stats.batch_occupancy_sum += len(active) / self.n_slots
         for i in active:
             req = self._slots[i]

@@ -12,6 +12,7 @@ import json
 import os
 import subprocess
 import sys
+import tomllib
 
 import pytest
 
@@ -100,10 +101,6 @@ class TestDictRoundTrip:
         assert spec_to_dict(spec2) == d
 
     def test_toml_round_trip_through_real_parser(self):
-        try:
-            import tomllib
-        except ModuleNotFoundError:
-            tomllib = pytest.importorskip("tomli")
         d = spec_to_dict(_full_spec())
         parsed = tomllib.loads(dumps_toml(d))
         assert spec_to_dict(spec_from_dict(parsed)) == d
@@ -388,9 +385,8 @@ priority = 1
 
     def test_diff_lines_are_field_aware(self):
         from repro.core.specfile import diff_spec_dicts
-        import tomli
 
-        lines = diff_spec_dicts(tomli.loads(self.A), tomli.loads(self.B))
+        lines = diff_spec_dicts(tomllib.loads(self.A), tomllib.loads(self.B))
         assert "~ pools.default.size: 4 -> 2" in lines
         assert "- tasks[math.sin].timeout_s = 5" in lines
         assert any(line.startswith("+ tasks[math.cos].fn") for line in lines)
@@ -399,9 +395,8 @@ priority = 1
 
     def test_identical_specs_diff_empty(self):
         from repro.core.specfile import diff_spec_dicts
-        import tomli
 
-        assert diff_spec_dicts(tomli.loads(self.A), tomli.loads(self.A)) == []
+        assert diff_spec_dicts(tomllib.loads(self.A), tomllib.loads(self.A)) == []
 
     def test_cli_exit_codes_and_output(self, tmp_path):
         a = tmp_path / "a.toml"
@@ -426,10 +421,9 @@ priority = 1
         """A v1 file (int pool shorthand) diffed against its v2 twin is
         equivalent apart from the version note."""
         from repro.core.specfile import diff_spec_dicts
-        import tomli
 
         v1 = "version = 1\n[[tasks]]\nfn = \"math.sin\"\n[pools]\ndefault = 4\n"
         v2 = "version = 2\n[[tasks]]\nfn = \"math.sin\"\n[pools.default]\nsize = 4\n"
-        lines = diff_spec_dicts(tomli.loads(v1), tomli.loads(v2))
+        lines = diff_spec_dicts(tomllib.loads(v1), tomllib.loads(v2))
         assert lines and lines[0].startswith("~ version: 1 -> 2")
         assert len(lines) == 1  # migrated bodies agree

@@ -689,6 +689,22 @@ class TestBenchTrajectory:
         assert "python" in doc["env"]
         assert doc["commit"] is None or len(doc["commit"]) == 40
 
+    def test_env_fingerprint_starts_no_backend(self):
+        """A recording process must leave the chip to the measuring one."""
+        import os
+        import subprocess
+        import sys
+
+        code = ("from jax._src import xla_bridge; "
+                "from repro.observe import env_fingerprint; fp = env_fingerprint(); "
+                "assert fp['jax'] and 'jax_backend' not in fp, fp; "
+                "assert not xla_bridge.backends_are_initialized()")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, env=env, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
     def test_failed_gate_fails_suite(self, tmp_path):
         from repro.observe import BenchRecorder, load_bench
 

@@ -3,6 +3,9 @@ Colmena-steered training driver (including preemption recovery)."""
 
 import json
 import os
+import subprocess
+import sys
+import time
 
 import numpy as np
 import pytest
@@ -18,6 +21,8 @@ from repro.launch.roofline import (
 )
 from repro.configs import get_config
 from repro.configs.base import SHAPES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestHloParsing:
@@ -111,6 +116,43 @@ class TestTrainingDriver:
         assert rep["preempted"]
         assert rep["workers_replaced"] >= 1        # node replaced
         assert rep["final_loss"] < rep["first_loss"]  # and training recovered
+
+    def test_failing_chunk_gives_up(self, monkeypatch):
+        """A chunk that always fails ends the run with an error instead of
+        resubmitting until the driver's timeout."""
+        import repro.launch.train as train
+
+        def broken_chunk(*args, **kwargs):
+            raise RuntimeError("no VJP on this backend")
+
+        monkeypatch.setattr(train, "train_chunk", broken_chunk)
+        t0 = time.monotonic()
+        rep = train.run(arch="gemma-2b", steps=20, chunk=10, seq=32, batch=4)
+        assert rep["error"] and "no VJP" in rep["error"]
+        assert rep["steps"] == 0
+        assert time.monotonic() - t0 < 60
+
+
+class TestCompileCache:
+    def _cache_dir(self, env_value):
+        code = ("import jax; from repro.launch.compile_cache import use_compile_cache; "
+                "print(use_compile_cache()); print(jax.config.jax_compilation_cache_dir)")
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_value:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_value
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, env=env, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return proc.stdout.split()
+
+    def test_env_var_wins(self, tmp_path):
+        used, configured = self._cache_dir(str(tmp_path))
+        assert used == configured == str(tmp_path)
+
+    def test_default_is_fixed_path_in_checkout(self):
+        used, configured = self._cache_dir(None)
+        assert used == configured == os.path.join(REPO, ".jax_cache")
 
 
 class TestReportRendering:

@@ -107,7 +107,8 @@ class TestSmallMeshDryRun:
             from repro.train.train_step import make_train_step
             from repro.launch.dryrun import _sds, _opt_pspecs
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             cfg = smoke_config({arch!r}).with_(d_model=64, n_heads=4, head_dim=16,
                                                d_ff=128, grad_accum=2)
             model = build_model(cfg)

@@ -1,0 +1,122 @@
+"""Compile-only rehearsals for one TPU v5e chip, without the chip.
+
+The TPU compiler is installed with JAX, so the main path's Pallas kernels
+are compiled here against a described ``v5e:2x2`` topology at their real
+widths (phi4-mini-3.8b: d_model 3072, 24 q / 8 kv heads of 128). The
+compiler refuses what interpret mode accepts — blocks that break the
+(8, 128) tiling rule, too much VMEM — so these tests catch it for free.
+Nothing runs: they say nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never at
+import time: only one process may load the TPU library, and every test
+worker imports this file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import compiled_kernels
+from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
+
+V5E_HBM_BYTES = 16e9
+B, H, KV, D, D_MODEL, MAX_LEN = 4, 24, 8, 128, 3072, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **jit_kwargs):
+    return jax.jit(fn, **jit_kwargs).lower(*args).compile()
+
+
+def test_decode_attention_compiles(one_chip):
+    bf = jnp.bfloat16
+    compiled = _compile(
+        decode_attention_pallas,
+        _sds((B, H, D), bf, one_chip),
+        _sds((B, KV, MAX_LEN, D), bf, one_chip),
+        _sds((B, KV, MAX_LEN, D), bf, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled_kernels(text) == {"decode_attention"}
+
+
+@pytest.mark.parametrize("rows", [(B, 1), (1, 700)], ids=["decode", "prefill"])
+def test_rmsnorm_compiles(one_chip, rows):
+    compiled = _compile(
+        rmsnorm_pallas,
+        _sds(rows + (D_MODEL,), jnp.bfloat16, one_chip),
+        _sds((D_MODEL,), jnp.bfloat16, one_chip),
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled_kernels(text) == {"rmsnorm"}
+
+
+def test_flash_attention_forward_compiles(one_chip):
+    bf = jnp.bfloat16
+    compiled = _compile(
+        flash_attention_pallas,
+        _sds((1, H, 512, D), bf, one_chip),
+        _sds((1, KV, 512, D), bf, one_chip),
+        _sds((1, KV, 512, D), bf, one_chip),
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled_kernels(text) == {"flash_attention"}
+
+
+def test_published_serve_step_compiles_with_pallas_kernels(one_chip, monkeypatch):
+    """The whole phi4-mini-3.8b serve step at published widths, bf16, as
+    ServingEngine compiles it on a TPU: both Pallas kernels present and
+    the program within one chip's HBM."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve import make_serve_step
+
+    # Off the TPU the ops pick their reference paths; steer them here.
+    monkeypatch.setenv("REPRO_ATTN_IMPL", "pallas")
+    monkeypatch.setenv("REPRO_NORM_IMPL", "pallas")
+    model = build_model(get_config("phi4-mini-3.8b"))
+    on_chip = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    compiled = _compile(
+        make_serve_step(model),
+        on_chip(model.shapes()),
+        on_chip(model.cache_shapes(B, MAX_LEN)),
+        _sds((B, 1), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip),
+        donate_argnums=(1,),                 # as ServingEngine donates the cache
+    )
+    assert {"decode_attention", "rmsnorm"} <= compiled_kernels(compiled.as_text())
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES

@@ -241,3 +241,43 @@ class TestServingEngine:
         alone1 = gen([p1])
         assert together[0] == alone0[0]
         assert together[1] == alone1[0 if 0 in alone1 else 1] or together[1] == list(alone1.values())[0]
+
+    def test_compiled_engine_matches_jitted(self):
+        """engine.compile() swaps in the AOT executable without changing
+        what is generated."""
+        cfg = smoke_config("phi4-mini-3.8b").with_(dtype="float32")
+        m = build_model(cfg)
+        params = m.init(jax.random.PRNGKey(0))
+        prompt = np.asarray([3, 1, 4, 1, 5], np.int32)
+
+        def gen(compile_first):
+            eng = ServingEngine(m, params, n_slots=2, max_len=32)
+            if compile_first:
+                eng.compile()
+            out = []
+            eng.on_finish = lambda r: out.append(r.generated)
+            eng.submit(Request(request_id=0, prompt=prompt, max_new_tokens=6))
+            stats = eng.run_until_drained()
+            assert stats.nonfinite_steps == 0
+            return out
+
+        assert gen(True) == gen(False)
+
+
+class TestServeLauncher:
+    def test_run_reports_served_tokens(self):
+        from repro.launch.serve import run
+
+        out = run("phi4-mini-3.8b", n_requests=3, n_slots=2, max_new=4, steer=False)
+        assert out["config"] == "phi4-mini-3.8b"
+        assert out["requests"] == 3 and out["tokens"] == 12
+        assert out["out_of_vocab_tokens"] == 0
+        assert out["nonfinite_logit_steps"] == 0
+        assert out["compile_s"] > 0
+        assert out["kernels"] == []          # CPU: the reference paths
+
+    def test_run_rejects_prompts_that_overflow_the_cache(self):
+        from repro.launch.serve import run
+
+        with pytest.raises(ValueError, match="overflow"):
+            run("phi4-mini-3.8b", n_requests=1, max_new=100)
