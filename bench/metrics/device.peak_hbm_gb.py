@@ -1,0 +1,5 @@
+"""The device's ``peak_bytes_in_use`` after the window, in GB (1e9 bytes)."""
+
+
+def read(run):
+    return run.peak_bytes / 1e9 if run.peak_bytes else None
