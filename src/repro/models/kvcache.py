@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..kernels import decode_attention
 from .layers import ParamDef, rope, shard
+from .scopes import ATTN, KV_CACHE_UPDATE
 
 
 def attn_cache_defs(cfg: ModelConfig, batch: int, max_len: int,
@@ -32,6 +33,7 @@ def attn_cache_defs(cfg: ModelConfig, batch: int, max_len: int,
     }
 
 
+@jax.named_scope(KV_CACHE_UPDATE)
 def update_cache(cache_k: jnp.ndarray, cache_v: jnp.ndarray,
                  k_new: jnp.ndarray, v_new: jnp.ndarray,
                  lengths: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -50,6 +52,7 @@ def update_cache(cache_k: jnp.ndarray, cache_v: jnp.ndarray,
     return cache_k, cache_v
 
 
+@jax.named_scope(ATTN)
 def decode_attention_step(
     cfg: ModelConfig,
     p: Dict[str, jnp.ndarray],

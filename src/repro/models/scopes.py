@@ -1,0 +1,32 @@
+"""Names of the ``jax.named_scope`` regions of the single-token decode step.
+
+XLA copies each scope into the ``op_name`` metadata of the instructions
+it lowers to, so a profile of the compiled step can charge device time
+to a part of the model by these names, whatever number a fusion gets.
+They are metadata only: the compiled program is the same without them.
+
+=================  ==========================================================
+``embed``          the token-embedding lookup
+``layers``         the scan over the layers; what no inner scope claims,
+                   such as the norms and the scan's write of each layer's
+                   cache into its stacked output, stays here
+``attn``           q/k/v projections, RoPE, the decode-attention kernel and
+                   the output projection
+``kv_cache.update``  ``kvcache.update_cache``: the new token's k and v
+                   written into the layer's cache
+``mlp``            the feed-forward block
+``unembed``        the final norm and the logits
+``sample``         the next token (argmax or sampling) and the finiteness
+                   check of the logits
+=================  ==========================================================
+"""
+
+EMBED = "embed"
+LAYERS = "layers"
+ATTN = "attn"
+KV_CACHE_UPDATE = "kv_cache.update"
+MLP = "mlp"
+UNEMBED = "unembed"
+SAMPLE = "sample"
+
+DECODE_SCOPES = (EMBED, LAYERS, ATTN, KV_CACHE_UPDATE, MLP, UNEMBED, SAMPLE)

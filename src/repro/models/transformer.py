@@ -32,6 +32,7 @@ from .layers import (
     unembed,
 )
 from . import moe as moe_mod
+from . import scopes
 from .kvcache import (
     attn_cache_defs,
     decode_attention_step,
@@ -205,10 +206,11 @@ def _decode_block(cfg: ModelConfig, p: Dict[str, Any], cache_l: Dict[str, jnp.nd
     attn_out, cache_l = decode_attention_step(cfg, p["attn"], cache_l, y, lengths)
     x = x + attn_out
     y = apply_norm(cfg, p["ln2"], x)
-    if cfg.family == "moe":
-        f, _ = moe_mod.moe_block(cfg, p["ffn"], y)
-    else:
-        f = mlp_block(cfg, p["ffn"], y)
+    with jax.named_scope(scopes.MLP):
+        if cfg.family == "moe":
+            f, _ = moe_mod.moe_block(cfg, p["ffn"], y)
+        else:
+            f = mlp_block(cfg, p["ffn"], y)
     return x + f, cache_l
 
 
@@ -267,24 +269,27 @@ def prefill(cfg: ModelConfig, params: Dict[str, Any], cache: Dict[str, Any],
 def decode_step(cfg: ModelConfig, params: Dict[str, Any], cache: Dict[str, Any],
                 tokens: jnp.ndarray, lengths: jnp.ndarray) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """tokens: (B, 1) int32; lengths: (B,) current cache fill. Returns
-    (logits (B, 1, V), updated cache)."""
-    x = embed_tokens(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
+    (logits (B, 1, V), updated cache). Its parts carry the named scopes
+    of ``scopes.DECODE_SCOPES``."""
+    with jax.named_scope(scopes.EMBED):
+        x = embed_tokens(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
 
-    if cfg.scan_layers:
-        def body(x, scanned):
-            lp, cl = scanned
-            x, cl = _decode_block(cfg, lp, cl, x, lengths)
-            return x, cl
+    with jax.named_scope(scopes.LAYERS):
+        if cfg.scan_layers:
+            def body(x, scanned):
+                lp, cl = scanned
+                x, cl = _decode_block(cfg, lp, cl, x, lengths)
+                return x, cl
 
-        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
-        cache = {"layers": new_layers}
-    else:
-        new_layers = []
-        for lp, cl in zip(params["layers"], cache["layers"]):
-            x, cl = _decode_block(cfg, lp, cl, x, lengths)
-            new_layers.append(cl)
-        cache = {"layers": new_layers}
-    x = apply_norm(cfg, params["final_norm"], x)
-    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = unembed(x, table, valid=cfg.vocab_size)
+            x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        else:
+            new_layers = []
+            for lp, cl in zip(params["layers"], cache["layers"]):
+                x, cl = _decode_block(cfg, lp, cl, x, lengths)
+                new_layers.append(cl)
+    cache = {"layers": new_layers}
+    with jax.named_scope(scopes.UNEMBED):
+        x = apply_norm(cfg, params["final_norm"], x)
+        table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+        logits = unembed(x, table, valid=cfg.vocab_size)
     return logits, cache

@@ -126,7 +126,6 @@ from .trace import (
     export_perfetto,
     load_jsonl,
     merge_jsonl,
-    profiled_call,
     span_summary,
     to_perfetto,
 )
@@ -152,7 +151,6 @@ __all__ = [
     "merge_jsonl",
     "MetricsExporter",
     "OpsServer",
-    "profiled_call",
     "render_diff",
     "Span",
     "span_summary",

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
 from ..models.model_api import Model
+from ..models.scopes import SAMPLE
 
 
 def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0) -> Callable:
@@ -16,15 +17,19 @@ def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0)
     (next_tokens (B,1), logits_finite (), cache).
 
     ``logits_finite`` is True when every logit of the step is finite; the
-    logits themselves stay on the device."""
+    logits themselves stay on the device. XLA names the jitted program's
+    module after this function, ``jit_serve_step``: profiles find the
+    step by that name."""
 
     def serve_step(params, cache, tokens, lengths, rng):
         logits, cache = model.decode_step(params, cache, tokens, lengths)
-        if greedy:
-            nxt = jnp.argmax(logits[:, -1], axis=-1)
-        else:
-            nxt = jax.random.categorical(rng, logits[:, -1] / temperature, axis=-1)
-        return nxt[:, None].astype(jnp.int32), jnp.isfinite(logits).all(), cache
+        with jax.named_scope(SAMPLE):
+            if greedy:
+                nxt = jnp.argmax(logits[:, -1], axis=-1)
+            else:
+                nxt = jax.random.categorical(rng, logits[:, -1] / temperature, axis=-1)
+            finite = jnp.isfinite(logits).all()
+        return nxt[:, None].astype(jnp.int32), finite, cache
 
     return serve_step
 
