@@ -14,6 +14,11 @@ the cache is read once per kv head. Every block's last two dims are either
 whole array dims (group, d) or (block_k, d) with block_k a multiple of 8,
 as the TPU tiling rule requires. Per-sequence lengths ride in SMEM through
 scalar prefetch.
+
+The cache may be a model's whole stacked cache, (L, B, KVH, S, d), with
+the layer to read named by an index that rides in SMEM beside the
+lengths: the k/v index maps pick that layer's blocks straight from the
+stack, so no per-layer slice is ever materialised for the call.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(
     len_ref,                      # (B,) int32, scalar-prefetched into SMEM
+    layer_ref,                    # (1,) int32, scalar-prefetched; read by the index maps
     q_ref, k_ref, v_ref,          # VMEM blocks
     o_ref,
     m_ref, l_ref, acc_ref,        # scratch
@@ -84,41 +90,46 @@ def _decode_kernel(
 
 def decode_attention_pallas(
     q: jnp.ndarray,          # (B, H, D)
-    k: jnp.ndarray,          # (B, KVH, S, D)
+    k: jnp.ndarray,          # (B, KVH, S, D), or (L, B, KVH, S, D) with ``layer``
     v: jnp.ndarray,
     lengths: jnp.ndarray,    # (B,) int32
+    layer: Optional[jnp.ndarray] = None,   # () int32: the layer of a stacked cache
     *,
     sm_scale: Optional[float] = None,
     window: Optional[int] = None,
     block_k: int = 1024,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
     b, h, d = q.shape
-    kvh, s = k.shape[1], k.shape[2]
+    n_layers, kvh, s = k.shape[0], k.shape[2], k.shape[3]
     group = h // kvh
     block_k = min(block_k, s)
     assert s % block_k == 0, (s, block_k)
     scale = sm_scale if sm_scale is not None else d ** -0.5
 
     qf = q.reshape(b * kvh, group, d)
-    kf = k.reshape(b * kvh, s, d)
-    vf = v.reshape(b * kvh, s, d)
+    kf = k.reshape(n_layers, b * kvh, s, d)
+    vf = v.reshape(n_layers, b * kvh, s, d)
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=scale, block_k=block_k, window=window or 0,
         kv_heads=kvh,
     )
+    kv_spec = pl.BlockSpec((pl.squeezed, 1, block_k, d),
+                           lambda bh, ki, lens, lyr: (lyr[0], bh, ki, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b * kvh, s // block_k),
             in_specs=[
-                pl.BlockSpec((1, group, d), lambda bh, ki, lens: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, d), lambda bh, ki, lens: (bh, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda bh, ki, lens: (bh, ki, 0)),
+                pl.BlockSpec((1, group, d), lambda bh, ki, lens, lyr: (bh, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
-            out_specs=pl.BlockSpec((1, group, d), lambda bh, ki, lens: (bh, 0, 0)),
+            out_specs=pl.BlockSpec((1, group, d), lambda bh, ki, lens, lyr: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, 128), jnp.float32),
                 pltpu.VMEM((group, 128), jnp.float32),
@@ -128,5 +139,5 @@ def decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((b * kvh, group, d), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(lengths.astype(jnp.int32), qf, kf, vf)
+    )(lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32), qf, kf, vf)
     return out.reshape(b, h, d)

@@ -4,6 +4,9 @@ The XLA path is a plain masked einsum: for one query token the score
 tensor is only (B, H, S) — bounded — and XLA fuses the mask+softmax
 chain well. The Pallas kernel wins on real TPUs by streaming the cache
 through VMEM once (see kernel.py); ``REPRO_ATTN_IMPL`` forces a choice.
+
+``layer`` reads one layer of a stacked (L, B, KVH, S, D) cache: the
+kernel indexes it in place, the reference path slices it out.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ def decode_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     lengths: jnp.ndarray,
+    layer: Optional[jnp.ndarray] = None,
     *,
     sm_scale: Optional[float] = None,
     window: Optional[int] = None,
@@ -39,13 +43,16 @@ def decode_attention(
     impl = impl or _default_impl()
     if impl == "pallas":
         return decode_attention_pallas(
-            q, k, v, lengths, sm_scale=sm_scale, window=window, block_k=block_k
+            q, k, v, lengths, layer, sm_scale=sm_scale, window=window, block_k=block_k
         )
     if impl == "interpret":
         return decode_attention_pallas(
-            q, k, v, lengths, sm_scale=sm_scale, window=window, block_k=block_k,
+            q, k, v, lengths, layer, sm_scale=sm_scale, window=window, block_k=block_k,
             interpret=True,
         )
     if impl == "ref":
+        if layer is not None:
+            k = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
+            v = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
         return decode_attention_ref(q, k, v, lengths, sm_scale=sm_scale, window=window)
     raise ValueError(f"unknown decode attention impl {impl!r}")

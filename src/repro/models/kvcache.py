@@ -4,6 +4,13 @@ Cache sharding prefers kv-head sharding over the model axis and falls
 back to head_dim sharding when the head count does not divide the axis
 (e.g. llama3's 8 kv heads on a 16-way model axis shard head_dim 128 ->
 8 per device), keeping the 32k-token cache within per-chip HBM.
+
+A decode step touches the cache only to write each sequence's new token
+in place and to read it in attention. The transformer's layer scan
+carries the whole stacked cache, (L, B, KV, S, hd), and hands each layer
+its index: the write and the decode kernel both address that layer
+inside the stack, so on a donated cache no step copies or rewrites a
+cache-sized buffer.
 """
 
 from __future__ import annotations
@@ -36,20 +43,45 @@ def attn_cache_defs(cfg: ModelConfig, batch: int, max_len: int,
 @jax.named_scope(KV_CACHE_UPDATE)
 def update_cache(cache_k: jnp.ndarray, cache_v: jnp.ndarray,
                  k_new: jnp.ndarray, v_new: jnp.ndarray,
-                 lengths: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Insert one token per sequence at position lengths[b].
+                 positions: jnp.ndarray,
+                 layer: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Write one token per sequence in place at position positions[b].
 
-    cache: (B, KV, S, hd); new: (B, 1, KV, hd); lengths: (B,).
-    Implemented as a one-hot scatter (SPMD-friendly: no gather/scatter
-    ops that would force resharding of the 32k cache)."""
-    S = cache_k.shape[2]
-    onehot = jax.nn.one_hot(lengths, S, dtype=cache_k.dtype)        # (B, S)
-    k_b = k_new.swapaxes(1, 2)                                       # (B, KV, 1, hd)
-    v_b = v_new.swapaxes(1, 2)
-    sel = onehot[:, None, :, None]                                   # (B, 1, S, 1)
-    cache_k = cache_k * (1 - sel) + sel * k_b
-    cache_v = cache_v * (1 - sel) + sel * v_b
-    return cache_k, cache_v
+    cache: (B, KV, S, hd), or (L, B, KV, S, hd) with ``layer`` the layer
+    written; new: (B, 1, KV, hd); positions: (B,). A position outside
+    [0, S) writes nothing: idle slots count on past the cache's end.
+    One scatter per operand moves B x KV x hd elements, which XLA does in
+    place on a buffer nothing else holds; on a sequence-sharded cache the
+    SPMD partitioner moves only the indices and the new token."""
+    return (_write_token(cache_k, k_new[:, 0], positions, layer),
+            _write_token(cache_v, v_new[:, 0], positions, layer))
+
+
+def _write_token(cache: jnp.ndarray, new: jnp.ndarray, positions: jnp.ndarray,
+                 layer: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """cache[(layer,) b, h, positions[b], :] = new[b, h] for each b and h.
+
+    new: (B, KV, hd). Each update is one hd-long row, the cache's minor
+    dimension, so the scatter asks for no layout but the cache's own."""
+    b, kvh, _ = new.shape
+    bb, hh = jnp.meshgrid(jnp.arange(b, dtype=jnp.int32),
+                          jnp.arange(kvh, dtype=jnp.int32), indexing="ij")
+    rows = [bb, hh, jnp.broadcast_to(positions.astype(jnp.int32)[:, None], (b, kvh))]
+    if layer is not None:
+        rows.insert(0, jnp.full((b, kvh), layer, jnp.int32))
+    dims = tuple(range(cache.ndim - 1))
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(2,), inserted_window_dims=dims,
+        scatter_dims_to_operand_dims=dims)
+    return jax.lax.scatter(
+        cache, jnp.stack(rows, axis=-1), new.astype(cache.dtype), dnums,
+        indices_are_sorted=True, unique_indices=True,
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _shard_cache(c: jnp.ndarray) -> jnp.ndarray:
+    stacked = ("layers",) if c.ndim == 5 else ()
+    return shard(c, *stacked, "batch", "cache_kv_heads", "cache_seq", None)
 
 
 @jax.named_scope(ATTN)
@@ -59,11 +91,16 @@ def decode_attention_step(
     cache_l: Dict[str, jnp.ndarray],
     x: jnp.ndarray,                      # (B, 1, D) normed input
     lengths: jnp.ndarray,                # (B,)
+    layer: Optional[jnp.ndarray] = None,
     *,
     window: Optional[int] = None,
     use_rope: bool = True,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """GQA attention for one new token against the cache."""
+    """GQA attention for one new token against the cache.
+
+    ``cache_l`` holds one layer's k and v, (B, KV, S, hd), or with
+    ``layer`` the whole stack, (L, B, KV, S, hd), of which that layer is
+    written and read; the cache returned has the shape it was given."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])                      # (B, 1, H, hd)
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -72,16 +109,15 @@ def decode_attention_step(
         q = rope(q, pos, cfg.rope_theta)
         k = rope(k, pos, cfg.rope_theta)
 
-    ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, lengths)
-    ck = shard(ck, "batch", "cache_kv_heads", "cache_seq", None)
-    cv = shard(cv, "batch", "cache_kv_heads", "cache_seq", None)
+    ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, lengths, layer)
+    ck, cv = _shard_cache(ck), _shard_cache(cv)
 
     # replicate the (tiny) single-token q across the model axis so the
     # score einsum keeps the (huge) cache sequence-sharded in place.
     q_rep = shard(q[:, 0], "batch", None, None)
     out = decode_attention(
         q_rep,                                                       # (B, H, hd)
-        ck, cv, lengths + 1, window=window,
+        ck, cv, lengths + 1, layer, window=window,
     )                                                                # (B, H, hd)
     out = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
     return shard(out, "batch", "seq", "embed"), {"k": ck, "v": cv}
@@ -124,8 +160,7 @@ def ring_decode_attention_step(
 
     slots = lengths % window
     ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, slots)
-    ck = shard(ck, "batch", "cache_kv_heads", "cache_seq", None)
-    cv = shard(cv, "batch", "cache_kv_heads", "cache_seq", None)
+    ck, cv = _shard_cache(ck), _shard_cache(cv)
     valid = jnp.minimum(lengths + 1, window)
     q_rep = shard(q[:, 0], "batch", None, None)
     out = decode_attention(q_rep, ck, cv, valid)

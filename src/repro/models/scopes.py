@@ -7,13 +7,13 @@ They are metadata only: the compiled program is the same without them.
 
 =================  ==========================================================
 ``embed``          the token-embedding lookup
-``layers``         the scan over the layers; what no inner scope claims,
-                   such as the norms and the scan's write of each layer's
-                   cache into its stacked output, stays here
+``layers``         the scan over the layers, which carries the stacked
+                   cache; what no inner scope claims, such as the norms,
+                   stays here
 ``attn``           q/k/v projections, RoPE, the decode-attention kernel and
                    the output projection
 ``kv_cache.update``  ``kvcache.update_cache``: the new token's k and v
-                   written into the layer's cache
+                   written in place into the layer's part of the cache
 ``mlp``            the feed-forward block
 ``unembed``        the final norm and the logits
 ``sample``         the next token (argmax or sampling) and the finiteness

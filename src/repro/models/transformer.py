@@ -36,7 +36,6 @@ from . import scopes
 from .kvcache import (
     attn_cache_defs,
     decode_attention_step,
-    update_cache,
 )
 
 
@@ -200,10 +199,12 @@ def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
 
 
 def _decode_block(cfg: ModelConfig, p: Dict[str, Any], cache_l: Dict[str, jnp.ndarray],
-                  x: jnp.ndarray, lengths: jnp.ndarray) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One layer of single-token decode. x: (B, 1, D)."""
+                  x: jnp.ndarray, lengths: jnp.ndarray,
+                  layer: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """One layer of single-token decode. x: (B, 1, D). With ``layer``,
+    ``cache_l`` is the stacked cache of every layer (see decode_step)."""
     y = apply_norm(cfg, p["ln1"], x)
-    attn_out, cache_l = decode_attention_step(cfg, p["attn"], cache_l, y, lengths)
+    attn_out, cache_l = decode_attention_step(cfg, p["attn"], cache_l, y, lengths, layer)
     x = x + attn_out
     y = apply_norm(cfg, p["ln2"], x)
     with jax.named_scope(scopes.MLP):
@@ -270,18 +271,25 @@ def decode_step(cfg: ModelConfig, params: Dict[str, Any], cache: Dict[str, Any],
                 tokens: jnp.ndarray, lengths: jnp.ndarray) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """tokens: (B, 1) int32; lengths: (B,) current cache fill. Returns
     (logits (B, 1, V), updated cache). Its parts carry the named scopes
-    of ``scopes.DECODE_SCOPES``."""
+    of ``scopes.DECODE_SCOPES``.
+
+    The layer scan carries the stacked cache and scans the layers' params
+    with their indices: each layer writes its token into the stack in
+    place and attends over its own layer of it, so on a donated cache the
+    cache returned is the input's buffer and no cache-sized copy is made."""
     with jax.named_scope(scopes.EMBED):
         x = embed_tokens(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
 
     with jax.named_scope(scopes.LAYERS):
         if cfg.scan_layers:
-            def body(x, scanned):
-                lp, cl = scanned
-                x, cl = _decode_block(cfg, lp, cl, x, lengths)
-                return x, cl
+            def body(carry, scanned):
+                x, stacked = carry
+                lp, layer = scanned
+                return _decode_block(cfg, lp, stacked, x, lengths, layer), None
 
-            x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+            layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            (x, new_layers), _ = jax.lax.scan(
+                body, (x, cache["layers"]), (params["layers"], layer_ids))
         else:
             new_layers = []
             for lp, cl in zip(params["layers"], cache["layers"]):

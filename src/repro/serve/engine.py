@@ -93,8 +93,10 @@ class ServingEngine:
 
         self._admit: "queue.Queue[Request]" = queue.Queue()
         self._slots: List[Optional[Request]] = [None] * n_slots
-        # The cache is donated: each step writes its successor in place. Without
-        # it, every step dispatched ahead of the device holds a whole cache.
+        # The cache is donated, so the step's output cache is its input's buffer:
+        # each layer writes its new token there in place and nothing copies it.
+        # Without donation, every step dispatched ahead of the device would hold
+        # a whole cache of its own.
         self._serve = jax.jit(make_serve_step(model), donate_argnums=(1,))
         self._cache = model.init_cache(n_slots, max_len)
         self._lengths = jnp.zeros((n_slots,), jnp.int32)

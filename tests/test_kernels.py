@@ -116,6 +116,22 @@ class TestDecodeAttention:
         out = decode_attention(q, k, v, lengths, window=16, impl="interpret", block_k=16)
         assert rel_err(out, ref) < 1e-4
 
+    @pytest.mark.parametrize("layer", [0, 2, 3])
+    @pytest.mark.parametrize("impl", ["interpret", "ref"])
+    def test_layer_index_reads_its_layer_of_the_stack(self, layer, impl):
+        """With a layer index into a stacked (L, B, KV, S, d) cache, each
+        path reads that layer as if handed its slice."""
+        n_layers, b, h, kvh, s, d = 4, 2, 4, 2, 64, 16
+        key = jax.random.PRNGKey(layer)
+        q = jax.random.normal(key, (b, h, d))
+        k = jax.random.normal(jax.random.fold_in(key, 1), (n_layers, b, kvh, s, d))
+        v = jax.random.normal(jax.random.fold_in(key, 2), (n_layers, b, kvh, s, d))
+        lengths = jnp.asarray([1, s], jnp.int32)
+        ref = decode_attention(q, k[layer], v[layer], lengths, impl="ref")
+        out = jax.jit(lambda l: decode_attention(q, k, v, lengths, l, impl=impl, block_k=16))(
+            jnp.int32(layer))
+        assert rel_err(out, ref) < 1e-4
+
 
 class TestRglruScan:
     @given(st.sampled_from([1, 3]), st.sampled_from([16, 64, 96]),

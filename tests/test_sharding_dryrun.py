@@ -133,3 +133,51 @@ class TestSmallMeshDryRun:
         assert proc.returncode == 0, proc.stderr[-3000:]
         out = json.loads(proc.stdout.strip().splitlines()[-1])
         assert out["flops"] > 0
+
+    def test_decode_writes_a_sequence_sharded_cache_in_place(self):
+        """The decode step's in-place cache write partitions without
+        gathering the cache: with 2 kv heads on a 4-way model axis the
+        cache shards its sequence, and only the new token, its indices
+        and the query cross devices."""
+        code = textwrap.dedent("""
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+            import jax, json, re
+            from repro.configs import smoke_config
+            from repro.configs.base import ShapeConfig
+            from repro.models import mesh_context
+            from repro.models.model_api import build_model
+            from repro.serve.decode import make_dryrun_serve_step
+            from repro.launch.dryrun import _sds
+
+            mesh = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
+            cfg = smoke_config("yi-6b").with_(d_model=64, n_heads=4, n_kv_heads=2,
+                                              head_dim=16, d_ff=128)
+            model = build_model(cfg)
+            B, S = 4, 64
+            with mesh_context(mesh, cfg):
+                c_specs = model.cache_pspecs(mesh, B, S)
+                p_sds = _sds(model.shapes(), model.pspecs(mesh), mesh)
+                c_sds = _sds(model.cache_shapes(B, S), c_specs, mesh)
+                io = model.input_specs(ShapeConfig("d", S, B, "decode"), mesh)
+                compiled = jax.jit(make_dryrun_serve_step(model), donate_argnums=(1,)).lower(
+                    p_sds, c_sds, io["tokens"], io["lengths"]).compile()
+            moved = []
+            for m in re.finditer(r"= \\w+\\[([\\d,]*)\\]\\S* (all-gather|all-to-all|collective-permute)\\(",
+                                 compiled.as_text()):
+                n = 1
+                for d in filter(None, m.group(1).split(",")):
+                    n *= int(d)
+                moved.append(n)
+            print(json.dumps({"spec": [str(a) for a in c_specs["layers"]["k"]],
+                              "moved": moved}))
+        """)
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, env=env, timeout=420)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert out["spec"][3] == "model"                     # the sequence is sharded
+        per_device_layer = (4 // 2) * 2 * (64 // 4) * 16     # B/data x KV x S/model x hd
+        assert max(out["moved"], default=0) < per_device_layer

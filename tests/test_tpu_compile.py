@@ -15,6 +15,7 @@ worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 
 V5E_HBM_BYTES = 16e9
-B, H, KV, D, D_MODEL, MAX_LEN = 4, 24, 8, 128, 3072, 2048
+B, H, KV, D, D_MODEL, MAX_LEN, N_LAYERS = 4, 24, 8, 128, 3072, 2048, 32
 
 
 @pytest.fixture(scope="module")
@@ -55,17 +56,19 @@ def _compile(fn, *args, **jit_kwargs):
 
 
 def test_decode_attention_compiles(one_chip):
+    """One layer's cache, and one layer read by index from the stack."""
     bf = jnp.bfloat16
-    compiled = _compile(
-        decode_attention_pallas,
-        _sds((B, H, D), bf, one_chip),
-        _sds((B, KV, MAX_LEN, D), bf, one_chip),
-        _sds((B, KV, MAX_LEN, D), bf, one_chip),
-        _sds((B,), jnp.int32, one_chip),
-    )
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert compiled_kernels(text) == {"decode_attention"}
+    q, lengths = _sds((B, H, D), bf, one_chip), _sds((B,), jnp.int32, one_chip)
+    layer_cache = _sds((B, KV, MAX_LEN, D), bf, one_chip)
+    stacked = _sds((N_LAYERS, B, KV, MAX_LEN, D), bf, one_chip)
+    for compiled in (
+        _compile(decode_attention_pallas, q, layer_cache, layer_cache, lengths),
+        _compile(decode_attention_pallas, q, stacked, stacked, lengths,
+                 _sds((), jnp.int32, one_chip)),
+    ):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text
+        assert compiled_kernels(text) == {"decode_attention"}
 
 
 @pytest.mark.parametrize("rows", [(B, 1), (1, 700)], ids=["decode", "prefill"])
@@ -95,8 +98,10 @@ def test_flash_attention_forward_compiles(one_chip):
 
 def test_published_serve_step_compiles_with_pallas_kernels(one_chip, monkeypatch):
     """The whole phi4-mini-3.8b serve step at published widths, bf16, as
-    ServingEngine compiles it on a TPU: both Pallas kernels present and
-    the program within one chip's HBM."""
+    ServingEngine compiles it on a TPU: both Pallas kernels present, the
+    program within one chip's HBM, and the cache written in place: no
+    scratch buffer as large as one layer's cache, and no copy or
+    multiply of a cache-sized buffer."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.serve import make_serve_step
@@ -120,3 +125,23 @@ def test_published_serve_step_compiles_with_pallas_kernels(one_chip, monkeypatch
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES
+    layer_kv_bytes = 2 * B * KV * MAX_LEN * D * 2
+    assert mem.temp_size_in_bytes < layer_kv_bytes
+    assert _cache_sized_copies(compiled.as_text()) == []
+
+
+# An instruction's name, its result's type (a tuple for a multi-output
+# fusion) and its opcode.
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([\w.-]+) = (\(.*?\)|\S+) ([\w-]+)\(", re.M)
+
+
+def _cache_sized_copies(hlo_text):
+    """Copies, and multiply fusions, with a result of the shape of the
+    stacked cache, of one layer's cache, or of the kernel's view of them."""
+    cache_dims = {f"{N_LAYERS},{B},{KV},{MAX_LEN},{D}", f"{B},{KV},{MAX_LEN},{D}",
+                  f"{N_LAYERS},{B * KV},{MAX_LEN},{D}", f"{B * KV},{MAX_LEN},{D}",
+                  f"1,{B},{KV},{MAX_LEN},{D}"}
+    return [name for name, result, opcode in _INSTRUCTION.findall(hlo_text)
+            if cache_dims & set(re.findall(r"\[([\d,]*)\]", result)) and (
+                opcode in ("copy", "copy-start", "copy-done")
+                or (opcode == "fusion" and ("multiply" in name or "copy" in name)))]
