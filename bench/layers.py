@@ -1,20 +1,11 @@
-"""Run a cell traced, as ``bench/run.py`` does, and split it by layer.
+"""A traced window split by layer, and short traces of a cell's step for the tests.
 
-    python bench/layers.py --workload <cell> --seed <n> --seconds <s> [--out <file.json>]
     python bench/layers.py --workload <cell> --seed <n> --record <dir>
 
-The first form makes the same run as ``bench/run.py --trace 1``, with the
-same set-up, window and comparison, and reads two more things from it: the
-engine's ``serve.*`` spans in the window's trace (``bench/spans.py``) and
-the optimized HLO text of the serve step (``bench/scopes.py``). It logs the
-host's time by span, the idle time by the span that held it, and the
-serve step's device time by named scope, and reports the per-layer
-metrics that read them (``engine.host_ms``, ``engine.prefill_token_ms``,
-``model.cache_write_ms``, ``step.copy_ms``) beside ``tokens_per_s`` and
-``itl_p95_ms``. ``bench/run.py`` does not hand these to its readers yet,
-so this command wraps three of its steps: the step's compile (to keep its
-HLO text), the trace's reading (to read the spans before the trace is
-deleted) and the ``Run`` it builds (to carry both).
+``split`` logs what a traced ``bench/run.py`` run read from the engine's
+``serve.*`` spans (``bench/spans.py``) and the serve step's optimized HLO
+text (``bench/scopes.py``): the host's time by span, the idle time by the
+span that held it, and the serve step's device time by named scope.
 
 ``--record`` writes a short trace of the cell's step for the tests: a few
 decode steps and one admission, with the step's HLO text.
@@ -25,12 +16,10 @@ from __future__ import annotations
 import argparse
 import glob
 import gzip
-import json
 import os
 import shutil
 import sys
 import tempfile
-from dataclasses import dataclass, replace
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
@@ -39,12 +28,9 @@ if sys.path and Path(sys.path[0]).resolve() == ROOT / "bench":
     sys.path.pop(0)
 sys.path.insert(0, str(ROOT))
 
-from bench import run as bench_run  # noqa: E402
 from bench import scopes, spans  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 
-METRICS = ("engine.host_ms", "engine.prefill_token_ms", "model.cache_write_ms", "step.copy_ms")
-ALSO = ("tokens_per_s", "itl_p95_ms")
 MODULE = "jit_serve_step"
 
 
@@ -78,52 +64,7 @@ def split(layers: spans.Layers, hlo_text: Optional[str], log: Callable = print) 
     return out
 
 
-def run_traced(cell: bench_run.Cell, seed: int, seconds: float, log: Callable = print,
-               **kw) -> Dict[str, Any]:
-    """``bench_run.run_cell`` traced, with the engine's spans and the step's
-    HLO text handed to the readers of ``METRICS``."""
-    from repro.serve import ServingEngine
-
-    seen: Dict[str, Any] = {}
-    compile0, load0, run0 = ServingEngine.compile, trace_mod.load, bench_run.Run
-
-    def compile_(engine):
-        compiled = compile0(engine)
-        seen["hlo_text"] = compiled.as_text()
-        return compiled
-
-    def load_(log_dir):
-        seen["layers"] = spans.load(log_dir)
-        return load0(log_dir)
-
-    @dataclass
-    class LayerRun(run0):
-        hlo_text: Optional[str] = None
-        layers: Any = None
-
-        def __post_init__(self):
-            self.hlo_text, self.layers = seen.get("hlo_text"), seen.get("layers")
-            seen["run"] = self
-
-    units = {m["name"]: m["unit"] for m in cell.end_to_end}
-    extra = [{"name": m, "unit": "ms"} for m in METRICS] + [{"name": m, "unit": units[m]} for m in ALSO]
-    cell = replace(cell, per_layer=cell.per_layer + extra)
-    ServingEngine.compile, trace_mod.load, bench_run.Run = compile_, load_, LayerRun
-    try:
-        result = bench_run.run_cell(cell, seed, seconds, True, log=log, **kw)
-    finally:
-        ServingEngine.compile, trace_mod.load, bench_run.Run = compile0, load0, run0
-    run = seen.get("run")
-    if run is not None and run.layers is not None:
-        result["layers"] = split(run.layers, run.hlo_text, log)
-        stats = {k: getattr(run.stats_close, k, 0) - getattr(run.stats_open, k, 0)
-                 for k in ("steps", "admissions", "prefill_calls", "prefill_tokens")}
-        log(f"engine counters over the window: {stats}")
-        result["layers"]["counters"] = stats
-    return result
-
-
-def record(cell: bench_run.Cell, seed: int, out_dir: str, log: Callable = print, *,
+def record(cell, seed: int, out_dir: str, log: Callable = print, *,
            require_tpu: bool = True, cfg=None) -> None:
     """Trace three ``step()`` calls of the cell's engine at its full size: one
     that finishes a request, one that admits the next (one prefill call), one
@@ -135,6 +76,7 @@ def record(cell: bench_run.Cell, seed: int, out_dir: str, log: Callable = print,
     from repro.models import build_model
     from repro.serve import Request, ServingEngine
 
+    from bench import run as bench_run
     from bench import weights
 
     if require_tpu:
@@ -175,23 +117,15 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=0.0)
-    ap.add_argument("--out", help="also write the result, with the splits, to this JSON file")
-    ap.add_argument("--record", help="record a short trace of the cell's step into this directory")
+    ap.add_argument("--record", required=True,
+                    help="record a short trace of the cell's step into this directory")
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", os.path.join(tempfile.gettempdir(), "tpu_logs"))
     sys.path.insert(0, str(ROOT / "src"))
-    log = lambda m: print(m, flush=True)  # noqa: E731
-    cell = bench_run.find_cell(args.workload)
-    if args.record:
-        record(cell, args.seed, args.record, log)
-        return 0
-    result = run_traced(cell, args.seed, args.seconds, log)
-    if args.out:
-        Path(args.out).parent.mkdir(parents=True, exist_ok=True)
-        Path(args.out).write_text(json.dumps(result))
-    result.pop("layers", None)
-    print(json.dumps(result), flush=True)
+    from bench import run as bench_run
+
+    record(bench_run.find_cell(args.workload), args.seed, args.record,
+           log=lambda m: print(m, flush=True))
     return 0
 
 

@@ -1,4 +1,6 @@
-"""Plain float32 reference of the dense decoder family (phi4-mini, yi).
+"""Model module of the dense decoder family (phi4-mini, yi): its plain
+float32 reference and its counts, by the convention of
+``bench/reference/__init__.py``.
 
 Pre-norm blocks: RMSNorm, grouped-query attention with rotary positions
 (the rotate-half form over the whole head), SwiGLU MLP, final RMSNorm and
@@ -14,19 +16,29 @@ It reads the weights the benchmark made (bf16, in the program's tree
 layout) and upcasts one layer at a time. ``mode="fp8"`` is the control:
 the same computation with every operand of every matrix product rounded to
 float8 e4m3 with one scale per tensor.
+
+Every layer runs the decode-attention kernel over the whole valid length,
+and a token's operations are ``bench/flops.py``'s.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.flops import token_flops  # noqa: F401  (this family's count, re-exported)
+
 BUCKET = 512            # sequences are padded to a multiple of this
 VOCAB_BLOCK = 8192      # unembedding rows per block
+
+
+def attended(cfg: Dict[str, Any], length: int) -> List[int]:
+    """Positions that a slot of valid length ``length`` attends, in each layer."""
+    return [length] * cfg["n_layers"]
 
 
 def _q(x: jnp.ndarray, mode: str) -> jnp.ndarray:

@@ -15,10 +15,15 @@ JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 compared beside its limit.
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration in ``bench/configs/<config>.json``, its traffic in
-``bench/traffic/<traffic>.json``, its limits in ``bench/limits/<cell>.json``
-and each metric's reader in ``bench/metrics/<metric>.py``. ``--trace 0``
-reports the cell's end-to-end metrics, ``--trace 1`` its per-layer ones.
+configuration in ``bench/configs/<config>.json``, the configuration's model
+module (its ``"reference"``) in ``bench/reference/<module>.py``, its traffic
+in ``bench/traffic/<traffic>.json``, its limits in
+``bench/limits/<cell>.json`` and each metric's reader in
+``bench/metrics/<metric>.py``. ``--trace 0`` reports the cell's end-to-end
+metrics, ``--trace 1`` its per-layer ones. A traced run hands the readers
+the window's profiler trace, the engine's spans in it (``bench/spans.py``)
+and the serve step's optimized HLO text, and logs the window by span and
+the step by named scope (``bench/layers.py``).
 
 The run exits non-zero and prints no result line where JAX's first device
 is not a TPU, where it has fewer chips than the cell asks for, or where the
@@ -32,6 +37,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -57,9 +63,7 @@ sys.path.insert(0, str(ROOT))
 from bench import traffic as traffic_mod  # noqa: E402
 
 SAMPLE_TOKENS = 384          # served tokens compared with the reference, at least
-MODEL_KEYS = ("family", "n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-              "vocab_size", "activation", "norm_eps", "norm_type", "norm_offset",
-              "embed_scale", "rope_theta", "tie_embeddings")
+COUNTERS = ("steps", "admissions", "prefill_calls", "prefill_tokens")   # logged over the window
 
 
 class NoChip(RuntimeError):
@@ -94,9 +98,13 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
+    file = configs[w["config"]]["file"]
+    config = _json(root / file)
+    if "reference" not in config:
+        raise KeyError(f"{file} names no model module: give it a \"reference\" key, the name "
+                       f"of a module under bench/reference/")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_json(root / configs[w["config"]]["file"]),
+        name=name, chips=int(w["chips"]), config=config,
         mix=_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
         limits=_json(root / "bench" / "limits" / f"{name}.json"),
         end_to_end=[m for m in spec["end_to_end"] if applies(m, name)],
@@ -111,6 +119,19 @@ def reader(metric: str, root: Path = ROOT) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+@functools.lru_cache(maxsize=None)
+def reference_module(name: str, root: Path):
+    """The model module ``bench/reference/<name>.py`` that a configuration
+    names: its ``served_gaps``, ``token_flops`` and ``attended`` (see
+    ``bench/reference/__init__.py``). Loaded once per process, so that its
+    jitted functions compile once."""
+    path = root / "bench" / "reference" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 # --------------------------------------------------------------------------- the client
@@ -254,7 +275,8 @@ class Client:
 @dataclass
 class Run:
     """What a metric reader gets."""
-    model: dict              # the configuration's model sizes
+    model: dict              # the configuration file's ``model`` keys, as the program runs them
+    reference: Any           # the configuration's model module (``reference_module``)
     seconds: float
     t_open: float
     t_close: float
@@ -265,6 +287,8 @@ class Run:
     peak_bytes: Optional[int]
     peaks: Optional[dict]
     trace: Any = None        # bench.trace.Reduced when traced
+    layers: Any = None       # bench.spans.Layers when traced
+    hlo_text: Optional[str] = None   # the serve step's optimized HLO text when traced
 
     def window_steps(self) -> List[StepCall]:
         return [s for s in self.rec.steps if self.t_open <= s.t0 < self.t_close]
@@ -282,8 +306,9 @@ def model_config(config: dict):
     return cfg
 
 
-def model_dict(cfg) -> dict:
-    return {k: getattr(cfg, k) for k in MODEL_KEYS}
+def model_dict(cfg, config: dict) -> dict:
+    """Every key of the configuration file's ``model``, as ``cfg`` holds it."""
+    return {k: getattr(cfg, k) for k in config["model"]}
 
 
 def check_device(chips: int):
@@ -297,14 +322,14 @@ def check_device(chips: int):
     return devices
 
 
-def compare(model: dict, params, client: Client, seed: int, control: bool = False) -> Dict[str, Any]:
-    """Widest logit gap of a seeded sample of finished requests, longest included.
+def compare(model: dict, ref, params, client: Client, seed: int,
+            control: bool = False) -> Dict[str, Any]:
+    """Widest logit gap of a seeded sample of finished requests, longest
+    included, by the model module ``ref``'s ``served_gaps``.
 
     With ``control`` the gaps are the float8 control's, read at the same
     positions in place of the served tokens', and ``program_gaps`` keeps the
     served tokens' for the log."""
-    from bench.reference import dense
-
     rec = client.rec
     done = sorted(rec.finished, key=lambda r: (-len(rec.served[r]), r))
     rng = np.random.default_rng(seed)
@@ -317,8 +342,8 @@ def compare(model: dict, params, client: Client, seed: int, control: bool = Fals
         n_tok += len(rec.served[rid])
     gaps, program_gaps = [], []
     for rid in picked:
-        out = dense.served_gaps(model, params, rec.reqs[rid].prompt,
-                                np.asarray(rec.served[rid], np.int32), control=control)
+        out = ref.served_gaps(model, params, rec.reqs[rid].prompt,
+                              np.asarray(rec.served[rid], np.int32), control=control)
         program_gaps.append(float(out["served"].max()))
         gaps.append(float(out["control" if control else "served"].max()))
     served_all = [t for rid in rec.finished for t in rec.served[rid]]
@@ -359,6 +384,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     conf = cell.config
     cfg = cfg if cfg is not None else model_config(conf)
+    ref = reference_module(conf["reference"], cell.root)
     n_slots, max_len = int(conf["n_slots"]), int(conf["max_len"])
     model = build_model(cfg)
     t = time.monotonic()
@@ -371,7 +397,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     engine = ServingEngine(model, params, n_slots=n_slots, max_len=max_len,
                            on_token=client.on_token, on_finish=client.on_finish)
     t = time.monotonic()
-    engine.compile()
+    compiled = engine.compile()
     log(f"set-up: step compiled or loaded in {time.monotonic() - t:.3f} s")
 
     # Collect what set-up left behind (tracing, compiling, the weights' tree)
@@ -407,15 +433,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.callbacks.remove(on_gc)
     gc_in_window = [(a, -b) for a, b in zip(full_gc[0::2], full_gc[1::2]) if a >= t_open]
     peak = (devices[0].memory_stats() or {}).get("peak_bytes_in_use")
-    reduced = None
+    reduced = layers = hlo_text = None
     if trace:
         jax.profiler.stop_trace()
+        from bench import spans
         from bench import trace as trace_mod
 
         t = time.monotonic()
-        reduced = trace_mod.load(tdir)
+        planes, host = trace_mod.events(tdir, spans.wanted)
+        window = trace_mod.clip(planes, host)
+        del planes
+        # The idle gaps are named once, by the engine's spans.
+        reduced = trace_mod.reduce_window(window)
+        layers = spans.reduce_window(window, host)
+        del window, host
         log(f"trace read in {time.monotonic() - t:.3f} s")
         shutil.rmtree(tdir, ignore_errors=True)
+        hlo_text = compiled.as_text()
 
     dev = devices[0]
     peaks = None
@@ -423,10 +457,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         from bench import peaks as peaks_mod
 
         peaks = peaks_mod.peaks(dev.device_kind)
-    run = Run(model=model_dict(cfg), seconds=seconds,
+    run = Run(model=model_dict(cfg, conf), reference=ref, seconds=seconds,
               t_open=t_open, t_close=t_close, setup_s=setup_s, rec=client.rec,
               stats_open=stats_open, stats_close=stats_close, peak_bytes=peak,
-              peaks=peaks, trace=reduced)
+              peaks=peaks, trace=reduced, layers=layers, hlo_text=hlo_text)
 
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
@@ -452,12 +486,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"engine's step() to return): max {max(lag, default=0.0):.6f} s, "
         f"mean {np.mean(lag) if lag else 0.0:.6f} s")
     log(f"peak_bytes_in_use {peak}; set-up {setup_s:.4f} s")
+    counters = {k: getattr(stats_close, k) - getattr(stats_open, k) for k in COUNTERS}
+    log(f"engine counters over the window: {json.dumps(counters)}")
+    if layers is not None:
+        from bench import layers as layers_mod
+
+        layers_mod.split(layers, hlo_text, log)
 
     # Free the engine's cache before the reference runs; the weights stay.
     engine = client.engine = None
     gc.collect()
     t = time.monotonic()
-    checks_raw = compare(run.model, params, client, seed, control=control)
+    checks_raw = compare(run.model, ref, params, client, seed, control=control)
     log(f"reference: {checks_raw['requests']} requests, {checks_raw['tokens']} tokens, "
         f"program gaps {checks_raw['program_gaps']} in {time.monotonic() - t:.3f} s")
     if control:
@@ -480,7 +520,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         result["breakdown"] = {"device_ops": reduced.top_ops(10),
-                               "idle_gaps": [[n, s] for n, s in reduced.gaps[:10]]}
+                               "idle_gaps": [[n, s] for n, s in layers.gaps[:10]]}
     result["checks"] = checks
     return result
 

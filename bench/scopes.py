@@ -10,7 +10,9 @@ operation of the trace to a part of the model, whatever number a fusion
 gets:
 
 - an instruction with an ``op_name`` belongs to the innermost scope of
-  the list found in it, or to ``step`` where it holds none of them;
+  the list found in it, or to ``step`` where it holds none of them. A
+  reader of a scope that another family's step adds passes the list with
+  that scope in it;
 - an instruction with no ``op_name`` was put in by XLA itself, such as a
   copy of a donated buffer, and belongs to ``xla.<opcode>``.
 """
@@ -19,7 +21,7 @@ from __future__ import annotations
 
 import re
 from collections import defaultdict
-from typing import Dict, NamedTuple
+from typing import Dict, NamedTuple, Sequence
 
 SCOPES = ("embed", "layers", "attn", "kv_cache.update", "mlp", "unembed", "sample")
 UNSCOPED = "step"
@@ -30,12 +32,13 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 class Place(NamedTuple):
-    scope: str          # innermost scope of SCOPES, UNSCOPED, or xla.<opcode>
+    scope: str          # innermost scope of the list, UNSCOPED, or xla.<opcode>
     primitive: str      # the last part of the op_name (the JAX primitive), or the opcode
 
 
-def op_places(hlo_text: str) -> Dict[str, Place]:
-    """Each instruction of the HLO text -> its scope and primitive."""
+def op_places(hlo_text: str, names: Sequence[str] = SCOPES) -> Dict[str, Place]:
+    """Each instruction of the HLO text -> its innermost scope of ``names``
+    and its primitive."""
     out: Dict[str, Place] = {}
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
@@ -47,7 +50,7 @@ def op_places(hlo_text: str) -> Dict[str, Place]:
             out[name] = Place(f"xla.{opcode}", opcode)
             continue
         parts = meta.group(1).split("/")
-        scope = next((p for p in reversed(parts) if p in SCOPES), UNSCOPED)
+        scope = next((p for p in reversed(parts) if p in names), UNSCOPED)
         out[name] = Place(scope, parts[-1])
     return out
 

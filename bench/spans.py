@@ -19,8 +19,6 @@ This module reads the same ``.xplane.pb`` for what the engine marks itself
 
 from __future__ import annotations
 
-import glob
-import os
 from collections import defaultdict
 from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
@@ -119,15 +117,11 @@ def by_program(ops: Sequence[Event], modules: Sequence[Event]) -> Dict[str, Dict
     return {p: trace.self_times(evs) for p, evs in groups.items()}
 
 
-def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
-                  host: Sequence[Event]) -> Layers:
-    """``devices``: per device, its (ops, modules) events; ``host``: span events.
-    Gaps are those of the first device, as ``trace.reduce_events`` takes them."""
-    windows = [e for e in host if e[0] == trace.WINDOW]
-    if not windows:
-        raise ValueError(f"the trace holds no {trace.WINDOW!r} span")
-    _, t0, dur = windows[0]
-    t1 = t0 + dur
+def reduce_window(w: trace.Window, host: Sequence[Event]) -> Layers:
+    """``w``: the trace cut to the window (``trace.clip``); ``host``: span
+    events. Gaps are those of the first device, as ``trace.reduce_window``
+    takes them."""
+    t0, t1 = w.t0, w.t1
     spans = [e for e in host if e[0] != trace.WINDOW and e[1] < t1 and e[1] + e[2] > t0]
 
     span_s: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
@@ -138,12 +132,7 @@ def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
 
     calls: Dict[str, int] = defaultdict(int)
     op_s: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
-    busy: List[Tuple[float, float]] = []
-    for k, (ops, modules) in enumerate(devices):
-        ops_in = [(trace.op_name(n), max(s, t0), min(s + d, t1) - max(s, t0))
-                  for n, s, d in ops if s < t1 and s + d > t0]
-        if k == 0:
-            busy = trace.union((s, s + d) for _, s, d in ops_in)
+    for ops_in, modules in zip(w.ops, w.modules):
         for n, s, _ in modules:
             if t0 <= s < t1:
                 calls[trace.module_name(n)] += 1
@@ -151,31 +140,19 @@ def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
             for op, x in sec.items():
                 op_s[prog][op] += x
 
-    edges = [t0] + [x for a, b in busy for x in (a, b)] + [t1]
-    gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
-    named, idle = name_gaps(gaps, spans)
-    return Layers(window_s=dur / 1e9, span_s={k: (int(v[0]), v[1]) for k, v in span_s.items()},
+    named, idle = name_gaps(w.gaps, spans)
+    return Layers(window_s=(t1 - t0) / 1e9,
+                  span_s={k: (int(v[0]), v[1]) for k, v in span_s.items()},
                   gaps=named, idle_by_span=idle, program_calls=dict(calls),
                   program_op_s={p: dict(v) for p, v in op_s.items()})
 
 
+def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
+                  host: Sequence[Event]) -> Layers:
+    """``devices``: per device, its (ops, modules) events; ``host``: span events."""
+    return reduce_window(trace.clip(devices, host), host)
+
+
 def load(log_dir: str) -> Layers:
     """Read the one ``.xplane.pb`` under ``log_dir`` and reduce it."""
-    from jax.profiler import ProfileData
-
-    paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
-    if len(paths) != 1:
-        raise FileNotFoundError(f"expected one .xplane.pb under {log_dir}, found {paths}")
-    data = ProfileData.from_file(paths[0])
-    devices, host = [], []
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            lines = {line.name: line for line in plane.lines}
-            get = lambda ln: [(e.name, e.start_ns, e.duration_ns)   # noqa: E731
-                              for e in lines[ln].events] if ln in lines else []
-            devices.append((get("XLA Ops"), get("XLA Modules")))
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host.extend((e.name, e.start_ns, e.duration_ns)
-                            for e in line.events if wanted(e.name))
-    return reduce_events(devices, host)
+    return reduce_events(*trace.events(log_dir, wanted))

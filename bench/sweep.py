@@ -58,7 +58,8 @@ def window(config, model, params, mix, seed, seconds, model_sizes):
     t_close = t_open + seconds
     client.run_until(t_close)
     rec = client.rec
-    r = run.Run(model=model_sizes, seconds=seconds, t_open=t_open, t_close=t_close,
+    r = run.Run(model=model_sizes, reference=run.reference_module(config["reference"], ROOT),
+                seconds=seconds, t_open=t_open, t_close=t_close,
                 setup_s=0.0, rec=rec, stats_open=stats_open, stats_close=replace(engine.stats),
                 peak_bytes=None, peaks=None)
     offered = sum(1 for q in tr.schedule if tr.preroll_s <= q.due < tr.preroll_s + seconds)
@@ -111,7 +112,8 @@ def main(argv=None) -> int:
         for sched in schedules:
             mix = dict(base, trace_seed=sched, arrivals=dict(base["arrivals"], rate_req_per_s=rate))
             t = time.monotonic()
-            row = window(config, model, params, mix, args.seed, args.seconds, run.model_dict(cfg))
+            row = window(config, model, params, mix, args.seed, args.seconds,
+                         run.model_dict(cfg, config))
             row["wall_s"] = time.monotonic() - t
             print(json.dumps(row), flush=True)
             ok = ok and row["sustained"]

@@ -19,6 +19,10 @@ From these it computes, within the window:
 - each program's executions and device time;
 - the idle gaps between busy intervals, each named by the host span that
   overlaps it most (``host.other`` where none does).
+
+``clip`` cuts the trace to the window once; ``reduce_window`` and
+``bench/spans.py``'s ``reduce_window`` both take what it returns. A run
+names its gaps by the engine's spans there, and asks this module for none.
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ import glob
 import os
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, Sequence, Tuple
 
 WINDOW = "bench.window"
 SPANS = ("client.submit", "engine.step", "bench.wait")
@@ -100,44 +104,61 @@ def self_times(ops: Sequence[Event]) -> Dict[str, float]:
     return dict(acc)
 
 
-def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
-                  host: Sequence[Event]) -> Reduced:
+@dataclass
+class Window:
+    """The trace cut to the window, as both reductions take it
+    (``spans.reduce_window`` too)."""
+    t0: float
+    t1: float
+    ops: List[List[Event]]                  # per device: ops by name, cut to the window, in start order
+    modules: List[Sequence[Event]]          # per device: program executions, as read
+    busy: List[List[Tuple[float, float]]]   # per device: the union of its ops' intervals
+
+    @property
+    def gaps(self) -> List[Tuple[float, float]]:
+        """The first device's idle intervals in the window, in ns."""
+        busy = self.busy[0] if self.busy else []
+        edges = [self.t0] + [x for a, b in busy for x in (a, b)] + [self.t1]
+        return [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+
+
+def clip(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
+         host: Sequence[Event]) -> Window:
     """``devices``: per device, its (ops, modules) events; ``host``: span events."""
     windows = [e for e in host if e[0] == WINDOW]
     if not windows:
         raise ValueError(f"the trace holds no {WINDOW!r} span")
     _, t0, dur = windows[0]
     t1 = t0 + dur
+    ops_in, busy = [], []
+    for ops, _ in devices:
+        cut = sorted(((op_name(n), max(s, t0), min(s + d, t1) - max(s, t0))
+                      for n, s, d in ops if s < t1 and s + d > t0), key=lambda e: (e[1], -e[2]))
+        ops_in.append(cut)
+        busy.append(union((s, s + d) for _, s, d in cut))
+    return Window(t0=t0, t1=t1, ops=ops_in, modules=[m for _, m in devices], busy=busy)
 
-    def inside(events: Sequence[Event]) -> List[Event]:
-        return [(op_name(n), max(s, t0), min(s + d, t1) - max(s, t0))
-                for n, s, d in events if s < t1 and s + d > t0]
 
+def reduce_window(w: Window, host: Sequence[Event] = ()) -> Reduced:
+    """The benchmark's device numbers; the idle gaps named by the
+    benchmark's spans in ``host``, or none where ``host`` is empty."""
     busy_ns, ops_all, mods = 0.0, defaultdict(float), defaultdict(lambda: [0, 0.0])
     calls: Dict[str, int] = defaultdict(int)
-    busy_per_device = []
-    for ops, modules in devices:
-        ops_in = inside(ops)
-        busy = union((s, s + d) for _, s, d in ops_in)
-        busy_per_device.append(busy)
+    for ops_in, modules, busy in zip(w.ops, w.modules, w.busy):
         busy_ns += sum(b - a for a, b in busy)
         for name, sec in self_times(ops_in).items():
             ops_all[name] += sec
         for name, _, _ in ops_in:
             calls[name] += 1
         for n, s, d in modules:
-            if t0 <= s < t1:
+            if w.t0 <= s < w.t1:
                 m = mods[module_name(n)]
                 m[0] += 1
                 m[1] += d / 1e9
-    n_dev = max(len(devices), 1)
+    n_dev = max(len(w.ops), 1)
     spans = [e for e in host if e[0] in SPANS]
     gaps = []
-    busy = busy_per_device[0] if busy_per_device else []
-    edges = [t0] + [x for a, b in busy for x in (a, b)] + [t1]
-    for a, b in zip(edges[0::2], edges[1::2]):
-        if b <= a:
-            continue
+    for a, b in (w.gaps if host else []):
         best, over = "host.other", 0.0
         for name, s, d in spans:
             o = min(b, s + d) - max(a, s)
@@ -145,14 +166,22 @@ def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
                 best, over = name, o
         gaps.append((best, (b - a) / 1e9))
     gaps.sort(key=lambda g: -g[1])
-    return Reduced(window_s=dur / 1e9, busy_s=busy_ns / n_dev / 1e9,
+    return Reduced(window_s=(w.t1 - w.t0) / 1e9, busy_s=busy_ns / n_dev / 1e9,
                    op_self_s=dict(ops_all),
                    modules={k: (v[0], v[1]) for k, v in mods.items()}, gaps=gaps,
-                   n_devices=len(devices), op_calls=dict(calls))
+                   n_devices=len(w.ops), op_calls=dict(calls))
 
 
-def load(log_dir: str) -> Reduced:
-    """Read the one ``.xplane.pb`` under ``log_dir`` and reduce it."""
+def reduce_events(devices: Sequence[Tuple[Sequence[Event], Sequence[Event]]],
+                  host: Sequence[Event]) -> Reduced:
+    """``devices``: per device, its (ops, modules) events; ``host``: span events."""
+    return reduce_window(clip(devices, host), host)
+
+
+def events(log_dir: str, keep: Callable[[str], bool]
+           ) -> Tuple[List[Tuple[List[Event], List[Event]]], List[Event]]:
+    """Read the one ``.xplane.pb`` under ``log_dir``: per TPU, its (ops,
+    modules) events, and the host's span events whose name ``keep`` takes."""
     from jax.profiler import ProfileData
 
     paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
@@ -160,7 +189,6 @@ def load(log_dir: str) -> Reduced:
         raise FileNotFoundError(f"expected one .xplane.pb under {log_dir}, found {paths}")
     data = ProfileData.from_file(paths[0])
     devices, host = [], []
-    wanted = set(SPANS) | {WINDOW}
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {line.name: line for line in plane.lines}
@@ -170,5 +198,11 @@ def load(log_dir: str) -> Reduced:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend((e.name, e.start_ns, e.duration_ns)
-                            for e in line.events if e.name in wanted)
-    return reduce_events(devices, host)
+                            for e in line.events if keep(e.name))
+    return devices, host
+
+
+def load(log_dir: str) -> Reduced:
+    """Read the one ``.xplane.pb`` under ``log_dir`` and reduce it."""
+    wanted = set(SPANS) | {WINDOW}
+    return reduce_events(*events(log_dir, wanted.__contains__))

@@ -18,7 +18,7 @@ from pathlib import Path
 import pytest
 
 import repro.serve.engine as engine_mod
-from bench import run
+from bench import flops, run
 from repro.configs import smoke_config
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -120,7 +120,7 @@ def test_sweep_window_reads_the_cell_metrics():
     model = build_model(cfg)
     params = weights.make(model.shapes(), 3, STD)
     mix = dict(cell.mix, arrivals=dict(cell.mix["arrivals"], rate_req_per_s=2.0))
-    row = sweep.window(cell.config, model, params, mix, 3, 2.0, run.model_dict(cfg))
+    row = sweep.window(cell.config, model, params, mix, 3, 2.0, run.model_dict(cfg, cell.config))
     assert row["rate"] == 2.0 and row["schedule"] == cell.mix["trace_seed"]
     assert row["offered_req_per_s"] > 0 and row["tokens_per_s"] > 0
     assert row["ttft_p95_s"] > 0 and isinstance(row["sustained"], bool)
@@ -153,6 +153,81 @@ def test_new_cell_and_metric_are_files_and_entries_only(tmp_path):
     assert res["correct"], res["checks"]
     assert res["metrics"]["requests_finished_per_s"]["value"] > 0
     assert {"tokens_per_s", "itl_p95_ms", "setup_s"} <= set(res["metrics"])
+
+
+OTHER_MODULE = '''"""The dense family under another name; its last layer attends half the length."""
+from bench.reference import dense
+
+CALLS = {"served_gaps": 0, "token_flops": 0, "attended": 0}
+
+
+def served_gaps(*args, **kw):
+    CALLS["served_gaps"] += 1
+    return dense.served_gaps(*args, **kw)
+
+
+def token_flops(cfg, context, served):
+    CALLS["token_flops"] += 1
+    return dense.token_flops(cfg, context, served)
+
+
+def attended(cfg, length):
+    CALLS["attended"] += 1
+    return dense.attended(cfg, length)[:-1] + [length // 2]
+'''
+
+
+def test_new_configuration_is_files_and_entries_only(tmp_path):
+    """A configuration of another family: its model module, its file with a
+    key that the dense files lack, and a cell, all new files and entries."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench")
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (tmp_path / "bench" / "reference" / "other.py").write_text(OTHER_MODULE)
+    config = json.loads((ROOT / "bench" / "configs" / "yi-6b.json").read_text())
+    config["reference"] = "other"
+    config["model"]["local_window"] = 0
+    (tmp_path / "bench" / "configs" / "other-6b.json").write_text(json.dumps(config))
+    (tmp_path / "bench" / "limits" / "other-6b.agent-decode.json").write_text(
+        json.dumps(SMOKE_LIMITS))
+    (tmp_path / "bench" / "metrics" / "attended_at_64.py").write_text(
+        "def read(run):\n    return sum(run.reference.attended(run.model, 64))\n")
+    (tmp_path / "bench" / "metrics" / "flops_at_64.py").write_text(
+        "def read(run):\n    return run.reference.token_flops(run.model, 64, served=True)\n")
+    spec["configs"].append({"name": "other-6b", "source": "https://huggingface.co/01-ai/Yi-6B",
+                            "file": "bench/configs/other-6b.json", "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "other-6b.agent-decode", "config": "other-6b",
+                              "traffic": "agent-decode", "chips": 1, "why": "test"})
+    for name in ("attended_at_64", "flops_at_64"):
+        spec["end_to_end"].append({"name": name, "unit": "count", "better": "higher",
+                                   "bound": 0.05, "source": "host_clock",
+                                   "workloads": ["other-6b.agent-decode"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell, cfg = smoke(run.find_cell("other-6b.agent-decode", root=tmp_path))
+    res = go(cell, cfg)
+    assert res["correct"], res["checks"]
+    other = run.reference_module("other", tmp_path)
+    assert other.CALLS == {"served_gaps": other.CALLS["served_gaps"], "token_flops": 1,
+                           "attended": 1}
+    assert other.CALLS["served_gaps"] > 0
+    # the readers get the cell's module and the file's whole model, at the
+    # program's smoke sizes: two layers, the second at half the length
+    model = run.model_dict(cfg, cell.config)
+    assert model["local_window"] == 0 and list(model) == list(config["model"])
+    assert res["metrics"]["attended_at_64"]["value"] == 64 + 32
+    assert res["metrics"]["flops_at_64"]["value"] == flops.token_flops(model, 64, served=True)
+    assert {"tokens_per_s", "itl_p95_ms", "setup_s"} <= set(res["metrics"])
+
+
+def test_configuration_without_a_model_module_is_an_error(tmp_path):
+    shutil.copytree(ROOT / "bench", tmp_path / "bench")
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    path = tmp_path / "bench" / "configs" / "yi-6b.json"
+    config = json.loads(path.read_text())
+    del config["reference"]
+    path.write_text(json.dumps(config))
+    with pytest.raises(KeyError, match="reference"):
+        run.find_cell("yi-6b.agent-decode", root=tmp_path)
+    assert run.find_cell("phi4-mini.agent-decode", root=tmp_path).config["reference"] == "dense"
 
 
 def command(cwd, env_extra):

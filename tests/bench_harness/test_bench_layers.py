@@ -8,6 +8,7 @@ from types import SimpleNamespace
 import pytest
 
 from bench import run, scopes, spans, trace
+from bench.reference import dense
 
 ROOT = Path(__file__).resolve().parents[2]
 TESTDATA = ROOT / "bench" / "testdata"
@@ -96,9 +97,55 @@ def test_hlo_ops_map_to_the_innermost_scope():
 
 
 def test_scope_list_matches_the_program():
-    from repro.models.scopes import DECODE_SCOPES
+    """Both directions between the benchmark's scope list and the program's.
 
-    assert scopes.SCOPES == DECODE_SCOPES
+    Every scope the benchmark reads is one the program marks. Every scope
+    the program marks that the list lacks (one that another family's step
+    adds) is read by a reader under ``bench/metrics`` that passes it to
+    ``op_places``, and passing it takes no operation of the step of any
+    configuration in ``BENCHMARK.json`` away from the write path that
+    ``model.cache_write_ms`` reads: the readers of the list still see
+    every operation they read."""
+    import ast
+    import json
+
+    import jax
+
+    from repro.configs import smoke_config
+    from repro.models import build_model
+    from repro.models.scopes import DECODE_SCOPES
+    from repro.serve import ServingEngine
+
+    assert set(scopes.SCOPES) <= set(DECODE_SCOPES)
+    extra = tuple(s for s in DECODE_SCOPES if s not in scopes.SCOPES)
+    named = {}
+    for path in sorted((ROOT / "bench" / "metrics").glob("*.py")):
+        src = path.read_text()
+        if "op_places(" in src:
+            named[path.stem] = {n.value for n in ast.walk(ast.parse(src))
+                                if isinstance(n, ast.Constant) and isinstance(n.value, str)}
+    for scope in extra:
+        assert any(scope in consts for consts in named.values()), \
+            f"the program marks {scope!r}, which no reader under bench/metrics passes to op_places"
+
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for arch in sorted({json.loads((ROOT / c["file"]).read_text())["arch"] for c in spec["configs"]}):
+        m = build_model(smoke_config(arch))
+        text = ServingEngine(m, m.init(jax.random.PRNGKey(0)), n_slots=2,
+                             max_len=64).compile().as_text()
+        listed, wider = scopes.op_places(text), scopes.op_places(text, scopes.SCOPES + extra)
+        write = {op for op, p in listed.items()
+                 if p.scope == "kv_cache.update" or p == ("layers", "dynamic_update_slice")}
+        assert write, arch
+        assert {op: wider[op] for op in write} == {op: listed[op] for op in write}, arch
+
+
+def test_a_scope_of_another_family_is_read_by_its_name():
+    line = ('  %fusion.7 = bf16[8]{0} fusion(%a), kind=kLoop, metadata={op_name='
+            '"jit(serve_step)/layers/while/body/mlp/experts/dot_general"}')
+    hlo = "HloModule jit_serve_step\n\nENTRY %main {\n" + line + "\n}\n"
+    assert scopes.op_places(hlo)["fusion.7"] == ("mlp", "dot_general")
+    assert scopes.op_places(hlo, scopes.SCOPES + ("experts",))["fusion.7"] == ("experts", "dot_general")
 
 
 def test_compiled_cpu_step_maps_write_path_ops():
@@ -116,7 +163,7 @@ def test_compiled_cpu_step_maps_write_path_ops():
 
 def parent_run(**over):
     stats = SimpleNamespace(steps=10, batch_occupancy_sum=10.0)
-    base = dict(model={}, seconds=1.0, t_open=0.0, t_close=1.0, setup_s=1.0, rec=run.Record(),
+    base = dict(model={}, reference=dense, seconds=1.0, t_open=0.0, t_close=1.0, setup_s=1.0, rec=run.Record(),
                 stats_open=stats, stats_close=stats, peak_bytes=None, peaks=None,
                 trace=trace.reduce_events([], [("bench.window", 0, 10)]))
     base.update(over)
@@ -141,26 +188,37 @@ def test_recorded_trace_reads_as_before():
     assert sum(new.program_op_s["jit_serve_step"].values()) == pytest.approx(sec, rel=1e-3)
 
 
-def test_traced_cpu_run_reads_the_engine_spans():
-    from repro.serve import ServingEngine
+def test_traced_cpu_run_reads_the_engine_spans(monkeypatch):
+    import json
 
     from bench import layers
     from test_bench_harness import STD, cell_named, smoke
 
-    compile0, run0 = ServingEngine.compile, run.Run
+    splits, logged = [], []
+
+    def split(got, hlo_text, log):
+        splits.append((got, hlo_text, layers_split(got, hlo_text, log)))
+
+    layers_split = layers.split
+    monkeypatch.setattr(layers, "split", split)
     cell, cfg = smoke(cell_named("phi4-mini.agent-decode"))
-    res = layers.run_traced(cell, 2147480001, 2.0, log=lambda m: None, require_tpu=False, cfg=cfg, std=STD)
-    assert ServingEngine.compile is compile0 and run.Run is run0 and trace.load is not None
+    res = run.run_cell(cell, 2147480001, 2.0, True, require_tpu=False, cfg=cfg, std=STD,
+                       log=logged.append)
     assert res["correct"], res["checks"]
     # no TPU plane on the CPU: the device readers stay silent, the host ones read
     assert {"engine.host_ms", "engine.prefill_token_ms"} <= set(res["metrics"])
     assert not {"model.cache_write_ms", "step.copy_ms"} & set(res["metrics"])
-    got = res["layers"]
-    assert got["counters"]["prefill_tokens"] == got["counters"]["prefill_calls"] > 0
-    assert got["span_ms"]["serve.dispatch"][0] == got["counters"]["steps"]
-    prefill_n, prefill_ms = got["span_ms"]["serve.prefill"]
+    ((got, hlo_text, out),) = splits
+    assert hlo_text.startswith("HloModule jit_serve_step")
+    assert res["breakdown"]["idle_gaps"] == [[n, s] for n, s in got.gaps[:10]]
+    prefix = "engine counters over the window: "
+    (line,) = [m for m in logged if m.startswith(prefix)]
+    counters = json.loads(line[len(prefix):])
+    assert counters["prefill_tokens"] == counters["prefill_calls"] > 0
+    assert out["span_ms"]["serve.dispatch"][0] == counters["steps"]
+    prefill_n, prefill_ms = out["span_ms"]["serve.prefill"]
     assert res["metrics"]["engine.prefill_token_ms"]["value"] == pytest.approx(
-        prefill_n * prefill_ms / got["counters"]["prefill_tokens"])
+        prefill_n * prefill_ms / counters["prefill_tokens"])
 
 
 def test_recorded_admission_trace():
@@ -191,6 +249,22 @@ def test_recorded_admission_trace():
     # the write path and the copies are half of phi4-mini's ~31 ms step
     assert 12 < got["model.cache_write_ms"] + got["step.copy_ms"] < 24
     assert got["engine.prefill_token_ms"] == pytest.approx(1e3 * layers.span_s["serve.prefill"][1])
+
+
+@pytest.mark.parametrize("recorded", ["phi4_steps", "phi4_admit"])
+def test_one_read_of_the_trace_gives_both_reductions(recorded):
+    """A traced run reads the trace once, keeping the engine's spans, cuts
+    it to the window once, and reduces it both ways: as each of the two
+    loaders would alone, less the gaps named by the benchmark's spans."""
+    from dataclasses import replace
+
+    devices, host = trace.events(str(TESTDATA / recorded), spans.wanted)
+    assert trace.reduce_events(devices, host) == trace.load(str(TESTDATA / recorded))
+    assert spans.reduce_events(devices, host) == spans.load(str(TESTDATA / recorded))
+    window = trace.clip(devices, host)
+    alone = trace.load(str(TESTDATA / recorded))
+    assert alone.gaps and trace.reduce_window(window) == replace(alone, gaps=[])
+    assert spans.reduce_window(window, host) == spans.load(str(TESTDATA / recorded))
 
 
 def test_record_writes_three_steps_and_the_step_text(tmp_path):
