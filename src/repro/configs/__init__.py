@@ -24,6 +24,7 @@ ARCH_MODULES = [
     "internvl2_1b",
     "recurrentgemma_2b",
     "whisper_large_v3",
+    "mellum2_12b_a2_5b",
 ]
 
 
@@ -49,4 +50,6 @@ ARCH_IDS = [
     "internvl2-1b",
     "recurrentgemma-2b",
     "whisper-large-v3",
+    "mellum2-12b-a2.5b",
+    "mellum2-12b-a2.5b-ep4",
 ]

@@ -33,8 +33,10 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     # --- MoE ---------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0               # experts held here: all, or this chip's share
     experts_per_token: int = 0
+    router_experts: int = 0          # experts the router scores (0: n_experts)
+    first_expert: int = 0            # router id of the first expert held here
     capacity_factor: float = 1.25
     moe_dispatch: str = "scatter"    # scatter (memory-light) | onehot (reference)
     router_aux_coef: float = 0.01
@@ -45,6 +47,17 @@ class ModelConfig:
     local_window: int = 0            # sliding-window size for local attention
     conv_width: int = 4              # temporal conv in griffin recurrent block
     rwkv_head_dim: int = 64
+
+    # --- window and full attention mixed --------------------------------------
+    # full_every = p: of each p layers the last attends in full, the others
+    # over the last local_window positions (0: every layer attends in full)
+    full_every: int = 0
+    # YaRN on the full-attention layers (yarn_factor 0: plain RoPE)
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0       # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0   # scale of cos and sin
 
     # --- enc-dec / multimodal -------------------------------------------------
     encoder_layers: int = 0          # whisper encoder depth
@@ -75,6 +88,12 @@ class ModelConfig:
         return -(-self.vocab_size // 256) * 256
 
     @property
+    def router_width(self) -> int:
+        """Experts the router scores; wider than ``n_experts`` where this
+        chip holds a share of them."""
+        return self.router_experts or self.n_experts
+
+    @property
     def is_subquadratic(self) -> bool:
         """True when long-context decode is architecturally tractable."""
         return self.family in ("rwkv6", "griffin")
@@ -86,12 +105,11 @@ class ModelConfig:
         H, KV, hd = self.n_heads, self.n_kv_heads, self.head_dim
         embed = V * d * (1 if self.tie_embeddings else 2)
         if self.family == "rwkv6":
-            per = 4 * d * d + 3 * d * f // 2 + 2 * d * f  # rough: tmix + cmix
-            per = 4 * d * d + 2 * d * f
+            per = 4 * d * d + 2 * d * f  # rough: tmix + cmix
             return embed + L * per
         attn = d * (H * hd) + 2 * d * (KV * hd) + (H * hd) * d
         if self.family == "moe":
-            ff = self.n_experts * 3 * d * f + d * self.n_experts
+            ff = self.n_experts * 3 * d * f + d * self.router_width
         elif self.activation in ("swiglu", "geglu"):
             ff = 3 * d * f
         else:
@@ -104,14 +122,17 @@ class ModelConfig:
 
     @property
     def n_active_params(self) -> int:
-        """Params touched per token (MoE: only routed experts)."""
+        """Params touched per token (MoE: only routed experts, and of a
+        share the routed experts held here, experts_per_token x held /
+        router width)."""
         if self.family != "moe":
             return self.n_params
         d, f, L = self.d_model, self.d_ff, self.n_layers
         H, KV, hd = self.n_heads, self.n_kv_heads, self.head_dim
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         attn = d * (H * hd) + 2 * d * (KV * hd) + (H * hd) * d
-        ff = self.experts_per_token * 3 * d * f + d * self.n_experts
+        routed = self.experts_per_token * self.n_experts // self.router_width
+        ff = routed * 3 * d * f + d * self.router_width
         return embed + L * (attn + ff)
 
 
@@ -183,7 +204,10 @@ def smoke_config(name: str) -> ModelConfig:
         scan_layers=cfg.scan_layers,
     )
     if cfg.family == "moe":
-        reduced.update(n_experts=4, experts_per_token=2)
+        reduced.update(n_experts=4, experts_per_token=2,
+                       router_experts=8 if cfg.router_width > cfg.n_experts else 0)
+    if cfg.full_every:
+        reduced.update(n_layers=2 * cfg.full_every, local_window=16)
     if cfg.family == "whisper":
         reduced.update(encoder_layers=2, encoder_seq=32)
     if cfg.family == "vlm":

@@ -35,7 +35,7 @@ from .layers import (
     shard,
     unembed,
 )
-from .kvcache import ring_cache_defs, ring_decode_attention_step
+from .kvcache import attn_cache_defs, decode_attention_step
 from .transformer import norm_def, apply_norm
 
 RGLRU_C = 8.0
@@ -178,7 +178,7 @@ def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     window = min(cfg.local_window, max_len)
     for i in range(cfg.n_layers):
         if is_attn_layer(cfg, i):
-            layers.append({"attn": ring_cache_defs(cfg, batch, window)})
+            layers.append({"attn": attn_cache_defs(cfg, batch, window)})   # a ring
         else:
             layers.append({
                 "conv": ParamDef((batch, cfg.conv_width - 1, cfg.d_model),
@@ -194,7 +194,9 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, lengths):
     for i, (lp, cl) in enumerate(zip(params["layers"], cache["layers"])):
         y = apply_norm(cfg, lp["ln1"], x)
         if is_attn_layer(cfg, i):
-            t, kv = ring_decode_attention_step(cfg, lp["temporal"]["kind_attn"], cl["attn"], y, lengths)
+            # local attention over a ring; griffin's sequences have no end
+            t, kv = decode_attention_step(cfg, lp["temporal"]["kind_attn"], cl["attn"], y, lengths,
+                                          ring=jnp.iinfo(jnp.int32).max)
             new_layers.append({"attn": kv})
         else:
             t, st = recurrent_block(cfg, lp["temporal"]["kind_rec"], y, cl)

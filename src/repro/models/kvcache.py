@@ -10,7 +10,10 @@ in place and to read it in attention. The transformer's layer scan
 carries the whole stacked cache, (L, B, KV, S, hd), and hands each layer
 its index: the write and the decode kernel both address that layer
 inside the stack, so on a donated cache no step copies or rewrites a
-cache-sized buffer.
+cache-sized buffer. A sliding-window layer keeps a ring of its last W
+positions in a stack of its own beside the full layers' stack, and is
+written and read in place the same way (``decode_attention_step``'s
+``ring``).
 """
 
 from __future__ import annotations
@@ -22,7 +25,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..kernels import decode_attention
-from .layers import ParamDef, rope, shard
+from .layers import ParamDef, rope, shard, yarn
 from .scopes import ATTN, KV_CACHE_UPDATE
 
 
@@ -93,23 +96,36 @@ def decode_attention_step(
     lengths: jnp.ndarray,                # (B,)
     layer: Optional[jnp.ndarray] = None,
     *,
-    window: Optional[int] = None,
-    use_rope: bool = True,
+    ring: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """GQA attention for one new token against the cache.
 
     ``cache_l`` holds one layer's k and v, (B, KV, S, hd), or with
     ``layer`` the whole stack, (L, B, KV, S, hd), of which that layer is
-    written and read; the cache returned has the shape it was given."""
+    written and read; the cache returned has the shape it was given.
+
+    With ``ring`` the cache is a sliding-window layer's ring of its last W
+    positions, and ``ring`` is the length of the sequences' full cache:
+    position n lives in slot n % W, the kernel reads the min(n + 1, W)
+    valid slots (attention over a set of keys is blind to their order, and
+    keys are roped at their absolute positions), and a slot at or past
+    ``ring`` is idle and writes nothing. Such layers take plain RoPE; the
+    full-attention layers take ``layers.yarn(cfg)``."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])                      # (B, 1, H, hd)
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if use_rope:
-        pos = lengths[:, None]
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
+    pos = lengths[:, None]
+    scaled = None if ring is not None else yarn(cfg)
+    q = rope(q, pos, cfg.rope_theta, scaled)
+    k = rope(k, pos, cfg.rope_theta, scaled)
 
-    ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, lengths, layer)
+    if ring is None:
+        slots, valid = lengths, lengths + 1
+    else:
+        w = cache_l["k"].shape[-2]
+        slots = jnp.where(lengths < ring, lengths % w, w)           # w: out of range, dropped
+        valid = jnp.minimum(lengths + 1, w)
+    ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, slots, layer)
     ck, cv = _shard_cache(ck), _shard_cache(cv)
 
     # replicate the (tiny) single-token q across the model axis so the
@@ -117,52 +133,7 @@ def decode_attention_step(
     q_rep = shard(q[:, 0], "batch", None, None)
     out = decode_attention(
         q_rep,                                                       # (B, H, hd)
-        ck, cv, lengths + 1, layer, window=window,
+        ck, cv, valid, layer,
     )                                                                # (B, H, hd)
-    out = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
-    return shard(out, "batch", "seq", "embed"), {"k": ck, "v": cv}
-
-
-# ---------------------------------------------------------------------------
-# Windowed (ring-buffer) cache for local attention (griffin)
-# ---------------------------------------------------------------------------
-
-
-def ring_cache_defs(cfg: ModelConfig, batch: int, window: int) -> Dict[str, ParamDef]:
-    kvh = cfg.n_kv_heads
-    shape = (batch, kvh, window, cfg.head_dim)
-    logical = ("batch", "cache_kv_heads", "cache_seq", None)
-    return {
-        "k": ParamDef(shape, logical, init="zeros"),
-        "v": ParamDef(shape, logical, init="zeros"),
-    }
-
-
-def ring_decode_attention_step(
-    cfg: ModelConfig,
-    p: Dict[str, jnp.ndarray],
-    cache_l: Dict[str, jnp.ndarray],
-    x: jnp.ndarray,
-    lengths: jnp.ndarray,
-) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Local attention with a fixed ``window``-slot ring buffer.
-
-    Keys are roped at their *absolute* position before storage; attention
-    over a set of (k, v) is permutation-invariant, so slot order never
-    matters and the buffer stays O(window) for 500k-token decodes."""
-    window = cache_l["k"].shape[2]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    pos = lengths[:, None]
-    q = rope(q, pos, cfg.rope_theta)
-    k = rope(k, pos, cfg.rope_theta)
-
-    slots = lengths % window
-    ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, slots)
-    ck, cv = _shard_cache(ck), _shard_cache(cv)
-    valid = jnp.minimum(lengths + 1, window)
-    q_rep = shard(q[:, 0], "batch", None, None)
-    out = decode_attention(q_rep, ck, cv, valid)
     out = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
     return shard(out, "batch", "seq", "embed"), {"k": ck, "v": cv}

@@ -16,6 +16,7 @@ dry run, guaranteeing they never drift apart.
 from __future__ import annotations
 
 import functools
+import math
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -267,14 +268,54 @@ def layer_norm(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, eps: float = 1e-5
     return ((xf - mean) * jax.lax.rsqrt(var + eps) * w + b).astype(x.dtype)
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding. x: (B, S, H, D), positions: (B, S)."""
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies (head_dim / 2,): each interpolated by
+    ``factor`` or kept, with a linear ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_len`` positions
+    (the correction range truncated to whole dimensions), as
+    ``transformers``' ``_compute_yarn_parameters`` computes them."""
+    dim = head_dim
+    extrapolation = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    interpolation = extrapolation / factor
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(original_len / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return interpolation * ramp + extrapolation * (1.0 - ramp)
+
+
+def yarn(cfg: ModelConfig) -> Optional[Tuple[np.ndarray, float]]:
+    """The full-attention layers' RoPE: YaRN's inverse frequencies and the
+    scale of cos and sin, or None (plain RoPE) where ``yarn_factor`` is 0."""
+    if not cfg.yarn_factor:
+        return None
+    freqs = yarn_inv_freq(cfg.head_dim, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_len,
+                          cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    return freqs, cfg.yarn_attention_factor
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         scaled: Optional[Tuple[np.ndarray, float]] = None) -> jnp.ndarray:
+    """Rotary embedding. x: (B, S, H, D), positions: (B, S). ``scaled``
+    (``yarn(cfg)``) replaces the frequencies of ``theta`` and scales cos
+    and sin."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaled is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(scaled[0], jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs        # (B, S, half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaled is not None:
+        cos, sin = cos * scaled[1], sin * scaled[1]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -383,8 +424,9 @@ def attention_block(
     if kv_override is not None:
         k, v = kv_override
     else:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        scaled = None if window else yarn(cfg)        # YaRN on full-attention layers
+        q = rope(q, positions, cfg.rope_theta, scaled)
+        k = rope(k, positions, cfg.rope_theta, scaled)
     cp = context_parallel_attention(cfg)
     if cp:
         q = shard(q, "batch", "seq_cp", None, None)

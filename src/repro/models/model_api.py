@@ -84,8 +84,8 @@ class Model:
     def cache_shapes(self, batch: int, max_len: int):
         return param_shapes(self.cache_defs(batch, max_len), self.cfg)
 
-    def decode_step(self, params, cache, tokens, lengths):
-        return self.mod.decode_step(self.cfg, params, cache, tokens, lengths)
+    def decode_step(self, params, cache, tokens, lengths, **kw):
+        return self.mod.decode_step(self.cfg, params, cache, tokens, lengths, **kw)
 
     # --------------------------------------------------------- input specs
     def input_specs(self, shape: ShapeConfig, mesh: Optional[Mesh] = None) -> Dict[str, Any]:

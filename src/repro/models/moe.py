@@ -14,6 +14,13 @@ Two dispatch implementations (selectable via ``cfg.moe_dispatch``):
 
 Load-balancing aux loss and router z-loss follow the standard
 formulation; capacity = ceil(T * k / E) * capacity_factor.
+
+A layer may hold a share of the experts (expert parallelism): the router
+scores all ``router_width`` of them and picks its top-k, and the layer
+computes the part of the result that its ``n_experts`` held experts, from
+``first_expert`` on, give. Assignments to experts held elsewhere add
+nothing here. The serve path (``moe_decode``) drops no token: each held
+expert runs over every token of the call, weighted by its gate.
 """
 
 from __future__ import annotations
@@ -26,12 +33,13 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .layers import ParamDef, shard
+from .scopes import MOE_EXPERTS, MOE_ROUTER
 
 
 def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
     return {
-        "router": ParamDef((d, E), ("embed_w", None)),
+        "router": ParamDef((d, cfg.router_width), ("embed_w", None)),
         "w_gate": ParamDef((E, d, f), ("experts", "embed_w", None)),
         "w_up": ParamDef((E, d, f), ("experts", "embed_w", None)),
         "w_down": ParamDef((E, f, d), ("experts", None, "embed_w")),
@@ -39,7 +47,7 @@ def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
 
 
 def _capacity(cfg: ModelConfig, n_tokens: int) -> int:
-    c = math.ceil(n_tokens * cfg.experts_per_token / cfg.n_experts * cfg.capacity_factor)
+    c = math.ceil(n_tokens * cfg.experts_per_token / cfg.router_width * cfg.capacity_factor)
     return max(4, -(-c // 4) * 4)   # round up to a multiple of 4
 
 
@@ -54,7 +62,9 @@ def _expert_mlp(cfg: ModelConfig, p: Dict[str, jnp.ndarray], xe: jnp.ndarray) ->
 
 
 def _router(cfg: ModelConfig, p: Dict[str, jnp.ndarray], x_flat: jnp.ndarray):
-    logits = jnp.einsum("td,de->te", x_flat, p["router"]).astype(jnp.float32)
+    """Softmax over all ``router_width`` scores in float32, top-k, the k
+    renormalised; ``top_idx`` holds router ids."""
+    logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32), p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_idx = jax.lax.top_k(probs, cfg.experts_per_token)
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
@@ -90,7 +100,14 @@ def _n_groups(T: int) -> int:
     return g if (g > 1 and T % g == 0) else 1
 
 
-def _dispatch_scatter(cfg: ModelConfig, p, x_flat, top_p, top_idx, C_unused):
+def _held(cfg: ModelConfig, top_idx: jnp.ndarray) -> jnp.ndarray:
+    """Router ids -> ids among the held experts; ``n_experts`` (one past
+    the last) for an expert held elsewhere."""
+    local = top_idx - cfg.first_expert
+    return jnp.where((local >= 0) & (local < cfg.n_experts), local, cfg.n_experts)
+
+
+def _dispatch_scatter(cfg: ModelConfig, p, x_flat, top_p, top_idx):
     T, d = x_flat.shape
     E, K = cfg.n_experts, cfg.experts_per_token
     G = _n_groups(T)
@@ -99,14 +116,14 @@ def _dispatch_scatter(cfg: ModelConfig, p, x_flat, top_p, top_idx, C_unused):
 
     def group_dispatch(x_g, top_p_g, top_idx_g):
         """Everything token-local within one data shard."""
-        flat_expert = top_idx_g.reshape(-1)                   # (Tl*K,)
+        flat_expert = _held(cfg, top_idx_g).reshape(-1)       # (Tl*K,)
         order = jnp.argsort(flat_expert)
         sorted_expert = flat_expert[order]
         sorted_token = (jnp.arange(Tl * K) // K)[order]
         sorted_prob = top_p_g.reshape(-1)[order]
-        starts = jnp.searchsorted(sorted_expert, jnp.arange(E), side="left")
+        starts = jnp.searchsorted(sorted_expert, jnp.arange(E + 1), side="left")
         rank = jnp.arange(Tl * K) - starts[sorted_expert]
-        keep = rank < C
+        keep = (rank < C) & (sorted_expert < E)
         dst = jnp.where(keep, sorted_expert * C + rank, E * C)   # overflow row
         buf = jnp.zeros((E * C + 1, d), x_g.dtype)
         buf = buf.at[dst].set(x_g[sorted_token])
@@ -138,8 +155,9 @@ def _dispatch_onehot(cfg: ModelConfig, p, x_flat, top_p, top_idx, C):
     T, d = x_flat.shape
     E, K = cfg.n_experts, cfg.experts_per_token
 
-    # (T, K, E) expert one-hot; position within expert via cumsum over tokens
-    oh = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)            # (T, K, E)
+    # (T, K, E) held-expert one-hot (zero for experts held elsewhere);
+    # position within expert via cumsum over tokens
+    oh = jax.nn.one_hot(_held(cfg, top_idx), E, dtype=jnp.float32)  # (T, K, E)
     flat_oh = oh.reshape(T * K, E)
     pos = (jnp.cumsum(flat_oh, axis=0) - flat_oh) * flat_oh       # rank per assignment
     pos = pos.sum(-1).reshape(T, K).astype(jnp.int32)             # (T, K)
@@ -164,6 +182,30 @@ def moe_block(cfg: ModelConfig, p: Dict[str, jnp.ndarray], x: jnp.ndarray
     if cfg.moe_dispatch == "onehot":
         y = _dispatch_onehot(cfg, p, x_flat, top_p, top_idx, C)
     else:
-        y = _dispatch_scatter(cfg, p, x_flat, top_p, top_idx, C)
+        y = _dispatch_scatter(cfg, p, x_flat, top_p, top_idx)
     y = y.reshape(B, S, d)
     return shard(y, "batch", "seq", "embed"), aux
+
+
+def moe_decode(cfg: ModelConfig, p: Dict[str, jnp.ndarray], x: jnp.ndarray, live: jnp.ndarray
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The serve path's expert layer, which drops no token: each held
+    expert runs over every token of the call, weighted by its gate (zero
+    where the token did not route to it). x: (B, S, d); live: (B,), the
+    slots not idle (their position inside the cache). Returns (y, counts):
+    counts (2,) int32 are the held experts that a live token routed to,
+    and the (live token, held expert) pairs."""
+    B, S, d = x.shape
+    x_flat = x.reshape(B * S, d)
+    with jax.named_scope(MOE_ROUTER):
+        top_p, top_idx, _ = _router(cfg, p, x_flat)
+        routed = jax.nn.one_hot(_held(cfg, top_idx), cfg.n_experts, dtype=jnp.float32)  # (T, K, E)
+        gates = jnp.einsum("tk,tke->te", top_p, routed)
+        hit = (routed.sum(1) > 0) & jnp.repeat(live, S)[:, None]                         # (T, E)
+        counts = jnp.stack([hit.any(0).sum(), hit.sum()]).astype(jnp.int32)
+    with jax.named_scope(MOE_EXPERTS):
+        gate = jnp.einsum("td,edf->etf", x_flat, p["w_gate"])
+        up = jnp.einsum("td,edf->etf", x_flat, p["w_up"])
+        out = jnp.einsum("etf,efd->etd", jax.nn.silu(gate) * up, p["w_down"])
+        y = jnp.einsum("te,etd->td", gates, out.astype(jnp.float32)).astype(x.dtype)
+    return shard(y.reshape(B, S, d), "batch", "seq", "embed"), counts

@@ -15,6 +15,10 @@ They are metadata only: the compiled program is the same without them.
 ``kv_cache.update``  ``kvcache.update_cache``: the new token's k and v
                    written in place into the layer's part of the cache
 ``mlp``            the feed-forward block
+``moe.router``     in ``mlp`` of an expert layer: the router's scores, its
+                   top-k and the gates of the experts held here
+``moe.experts``    in ``mlp`` of an expert layer: the held experts' MLPs
+                   and the gated sum of their outputs
 ``unembed``        the final norm and the logits
 ``sample``         the next token (argmax or sampling) and the finiteness
                    check of the logits
@@ -28,5 +32,7 @@ KV_CACHE_UPDATE = "kv_cache.update"
 MLP = "mlp"
 UNEMBED = "unembed"
 SAMPLE = "sample"
+MOE_ROUTER = "moe.router"
+MOE_EXPERTS = "moe.experts"
 
 DECODE_SCOPES = (EMBED, LAYERS, ATTN, KV_CACHE_UPDATE, MLP, UNEMBED, SAMPLE)

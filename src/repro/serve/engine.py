@@ -16,7 +16,9 @@ running each costs about 0.4 us (measured on a TPU v5e host). One
 - ``serve.admit``, only when a slot is filled, with one ``serve.prefill``
   per request placed in a slot (its serve-step calls and host round trips);
 - ``serve.dispatch``: launching the decode step;
-- ``serve.device_get``: the step's one device-to-host transfer;
+- ``serve.device_get``: the step's one device-to-host transfer (the next
+  tokens, the finiteness flag and, for a model with experts, the expert
+  layers' counts);
 - ``serve.bookkeeping``: the per-slot loop, with ``serve.hooks`` around
   each ``on_token``/``on_finish`` call (the caller's code).
 """
@@ -64,6 +66,8 @@ class EngineStats:
     admissions: int = 0                  # requests placed in a slot
     prefill_calls: int = 0               # serve-step calls that fed prompt tokens
     prefill_tokens: int = 0              # prompt tokens fed through those calls
+    experts_touched: int = 0             # decode steps: held experts a live token routed to, summed over layers
+    expert_pairs: int = 0                # decode steps: (live token, held expert) pairs, summed over layers
 
     @property
     def mean_occupancy(self) -> float:
@@ -145,9 +149,9 @@ class ServingEngine:
             tok_vec = np.asarray(self._tokens).copy()
             tok_vec[i, 0] = int(tok)
             self._tokens = jnp.asarray(tok_vec)
-            _, _, self._cache = self._serve(
+            self._cache = self._serve(
                 self.params, self._cache, self._tokens, self._lengths, self._rng
-            )
+            )[2]
             self.stats.prefill_calls += 1
             self.stats.prefill_tokens += 1
             lengths = np.asarray(self._lengths).copy()
@@ -168,20 +172,25 @@ class ServingEngine:
             return 0
         with TraceAnnotation("serve.dispatch"):
             self._rng, sub = jax.random.split(self._rng)
-            nxt, finite, self._cache = self._serve(self.params, self._cache, self._tokens, self._lengths, sub)
+            nxt, finite, self._cache, *counts = self._serve(
+                self.params, self._cache, self._tokens, self._lengths, sub)
             self._tokens = nxt
             self._lengths = self._lengths + 1
         with TraceAnnotation("serve.device_get"):
-            nxt_np, finite = jax.device_get((nxt, finite))
+            nxt_np, finite, counts = jax.device_get((nxt, finite, counts))
 
         with TraceAnnotation("serve.bookkeeping"):
-            self._bookkeeping(active, nxt_np, finite)
+            self._bookkeeping(active, nxt_np, finite, counts)
         return len(active)
 
-    def _bookkeeping(self, active: List[int], nxt_np: np.ndarray, finite: bool) -> None:
+    def _bookkeeping(self, active: List[int], nxt_np: np.ndarray, finite: bool,
+                     counts: List[np.ndarray]) -> None:
         self.stats.steps += 1
         if not finite:
             self.stats.nonfinite_steps += 1
+        if counts:
+            self.stats.experts_touched += int(counts[0][0])
+            self.stats.expert_pairs += int(counts[0][1])
         self.stats.batch_occupancy_sum += len(active) / self.n_slots
         for i in active:
             req = self._slots[i]

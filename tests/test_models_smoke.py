@@ -155,6 +155,7 @@ def test_param_counts_match_published_sizes():
         "internvl2-1b": 0.49e9,
         "recurrentgemma-2b": 2.6e9,
         "whisper-large-v3": 1.6e9,
+        "mellum2-12b-a2.5b": 12.15e9,
     }
     for arch, want in expected.items():
         got = get_config(arch).n_params

@@ -145,3 +145,54 @@ def _cache_sized_copies(hlo_text):
             if cache_dims & set(re.findall(r"\[([\d,]*)\]", result)) and (
                 opcode in ("copy", "copy-start", "copy-done")
                 or (opcode == "fusion" and ("multiply" in name or "copy" in name)))]
+
+
+def test_window_moe_serve_step_compiles_without_copying_weights(one_chip, monkeypatch):
+    """Mellum2's one-chip share at published widths, 8 slots x 2048: the
+    step reads its layers out of the stacked weights in place. No expert
+    stack, nor a period's slice of it, is copied in the step: only the
+    attention projections are laid out anew, as the dense steps do."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve import make_serve_step
+
+    monkeypatch.setenv("REPRO_ATTN_IMPL", "pallas")
+    monkeypatch.setenv("REPRO_NORM_IMPL", "pallas")
+    model = build_model(get_config("mellum2-12b-a2.5b-ep4"))
+    on_chip = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    slots = 8
+    compiled = _compile(
+        make_serve_step(model),
+        on_chip(model.shapes()),
+        on_chip(model.cache_shapes(slots, MAX_LEN)),
+        _sds((slots, 1), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((2,), jnp.uint32, one_chip),
+        donate_argnums=(1,),
+    )
+    text = compiled.as_text()
+    assert {"decode_attention", "rmsnorm"} <= compiled_kernels(text)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+    expert_bytes = 16 * 3 * 2304 * 896 * 2          # one layer's held experts
+    assert mem.temp_size_in_bytes < 4 * expert_bytes
+    copied = [result for result, opcode in _unfused(text)
+              if opcode in ("copy", "copy-start", "fusion")
+              and re.search(r"\[(\d+,)?16,(2304,896|896,2304)\]", result)]
+    assert copied == []
+
+
+def _unfused(hlo_text):
+    """(result type, opcode) of the instructions that make a buffer of their
+    own: those outside the computations that fusions call."""
+    fused = set(re.findall(r"calls=%([\w.-]+)", hlo_text))
+    out, inside = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and inside not in fused:
+            out.append((m.group(2), m.group(3)))
+    return out
