@@ -36,23 +36,25 @@ def decode_attention(
     layer: Optional[jnp.ndarray] = None,
     *,
     sm_scale: Optional[float] = None,
-    window: Optional[int] = None,
     impl: Optional[str] = None,
-    block_k: int = 1024,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
+    """``block_k`` overrides the kernel's block, which it otherwise works
+    out from the shapes (``kernel.block_for``); the CPU tests set it to
+    cover several blocks at small sizes."""
     impl = impl or _default_impl()
     if impl == "pallas":
         return decode_attention_pallas(
-            q, k, v, lengths, layer, sm_scale=sm_scale, window=window, block_k=block_k
+            q, k, v, lengths, layer, sm_scale=sm_scale, block_k=block_k
         )
     if impl == "interpret":
         return decode_attention_pallas(
-            q, k, v, lengths, layer, sm_scale=sm_scale, window=window, block_k=block_k,
+            q, k, v, lengths, layer, sm_scale=sm_scale, block_k=block_k,
             interpret=True,
         )
     if impl == "ref":
         if layer is not None:
             k = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
             v = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
-        return decode_attention_ref(q, k, v, lengths, sm_scale=sm_scale, window=window)
+        return decode_attention_ref(q, k, v, lengths, sm_scale=sm_scale)
     raise ValueError(f"unknown decode attention impl {impl!r}")

@@ -21,7 +21,6 @@ def decode_attention_ref(
     lengths: jnp.ndarray,    # (B,) int32 — valid cache entries per sequence
     *,
     sm_scale: Optional[float] = None,
-    window: Optional[int] = None,
 ) -> jnp.ndarray:
     b, h, d = q.shape
     kvh, s = k.shape[1], k.shape[2]
@@ -33,8 +32,6 @@ def decode_attention_ref(
                         preferred_element_type=jnp.float32) * scale
     pos = jnp.arange(s)[None, :]
     mask = pos < lengths[:, None]
-    if window is not None and window > 0:
-        mask &= pos >= (lengths[:, None] - window)
     mask4 = mask[:, None, None, :]
     scores = jnp.where(mask4, scores, -jnp.inf)
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))

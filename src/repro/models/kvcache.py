@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..kernels import decode_attention
+from ..kernels.decode_attention.kernel import block_for, last_filled_block
 from .layers import ParamDef, rope, shard, yarn
 from .scopes import ATTN, KV_CACHE_UPDATE
 
@@ -119,12 +120,8 @@ def decode_attention_step(
     q = rope(q, pos, cfg.rope_theta, scaled)
     k = rope(k, pos, cfg.rope_theta, scaled)
 
-    if ring is None:
-        slots, valid = lengths, lengths + 1
-    else:
-        w = cache_l["k"].shape[-2]
-        slots = jnp.where(lengths < ring, lengths % w, w)           # w: out of range, dropped
-        valid = jnp.minimum(lengths + 1, w)
+    w = cache_l["k"].shape[-2]
+    slots = lengths if ring is None else jnp.where(lengths < ring, lengths % w, w)  # w: dropped
     ck, cv = update_cache(cache_l["k"], cache_l["v"], k, v, slots, layer)
     ck, cv = _shard_cache(ck), _shard_cache(cv)
 
@@ -133,7 +130,34 @@ def decode_attention_step(
     q_rep = shard(q[:, 0], "batch", None, None)
     out = decode_attention(
         q_rep,                                                       # (B, H, hd)
-        ck, cv, valid, layer,
+        ck, cv, valid_entries(lengths, w), layer,
     )                                                                # (B, H, hd)
     out = jnp.einsum("bhk,hkd->bd", out, p["wo"])[:, None]
     return shard(out, "batch", "seq", "embed"), {"k": ck, "v": cv}
+
+
+def valid_entries(lengths: jnp.ndarray, cache_len: int) -> jnp.ndarray:
+    """The entries a decode step at ``lengths`` attends in a cache of
+    ``cache_len`` positions: the slot's n + 1, its new token's included, up
+    to the cache's end, so min(n + 1, W) in a ring of W."""
+    return jnp.minimum(lengths + 1, cache_len)
+
+
+def kv_blocks(cache: Any, lengths: jnp.ndarray) -> jnp.ndarray:
+    """(blocks the decode kernel fetches, blocks the caches hold) in one
+    decode step at ``lengths``, (2,) int32, summed over every attention
+    cache in ``cache``: each ``k`` leaf, one layer's (B, KV, S, hd) or a
+    stack of layers', (L, B, KV, S, hd). A count worked out from the
+    kernel's own block (``block_for``) and clamp (``last_filled_block``)
+    over the entries the step attends (``valid_entries``), not an
+    observation of the kernel's DMAs."""
+    total = jnp.zeros((2,), jnp.int32)
+    for path, k in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if path[-1] != jax.tree_util.DictKey("k"):
+            continue
+        n_layers = k.shape[0] if k.ndim == 5 else 1
+        kvh, s, hd = k.shape[-3:]
+        bk = block_for(s, kvh, hd, k.dtype.itemsize)
+        read = last_filled_block(valid_entries(lengths, s), bk, s // bk) + 1
+        total += n_layers * jnp.stack([read.sum(), lengths.shape[0] * (s // bk)])
+    return total

@@ -8,26 +8,32 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
+from ..models.kvcache import kv_blocks
 from ..models.model_api import Model
 from ..models.scopes import SAMPLE
 
 
 def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0) -> Callable:
     """Returns serve_step(params, cache, tokens, lengths, rng) ->
-    (next_tokens (B,1), logits_finite (), cache), and for a model with
-    experts a fourth output: the expert layers' counts (2,) int32, the held
-    experts that a live token routed to and the (token, held expert) pairs,
-    each summed over the layers (``moe.moe_decode``).
+    (next_tokens (B,1), step_stats, cache).
 
-    ``logits_finite`` is True when every logit of the step is finite; the
-    logits themselves stay on the device. XLA names the jitted program's
-    module after this function, ``jit_serve_step``: profiles find the
-    step by that name."""
+    ``step_stats`` is what the host fetches besides the tokens, in the same
+    transfer: ``finite`` () is True when every logit of the step is finite
+    (the logits themselves stay on the device); ``kv_blocks`` (2,) int32
+    the cache blocks that the decode-attention kernel fetched and that the
+    caches hold, each summed over the attending layers
+    (``kvcache.kv_blocks``); and for a model with experts ``experts`` (2,)
+    int32, the held experts that a live token routed to and the (token,
+    held expert) pairs, each summed over the layers (``moe.moe_decode``).
+    XLA names the jitted program's module after this function,
+    ``jit_serve_step``: profiles find the step by that name."""
     experts = model.cfg.family == "moe"
 
     def serve_step(params, cache, tokens, lengths, rng):
+        stats = {"kv_blocks": kv_blocks(cache, lengths)}
         if experts:
-            logits, cache, counts = model.decode_step(params, cache, tokens, lengths, counts=True)
+            logits, cache, stats["experts"] = model.decode_step(params, cache, tokens, lengths,
+                                                                counts=True)
         else:
             logits, cache = model.decode_step(params, cache, tokens, lengths)
         with jax.named_scope(SAMPLE):
@@ -35,9 +41,8 @@ def make_serve_step(model: Model, greedy: bool = True, temperature: float = 1.0)
                 nxt = jnp.argmax(logits[:, -1], axis=-1)
             else:
                 nxt = jax.random.categorical(rng, logits[:, -1] / temperature, axis=-1)
-            finite = jnp.isfinite(logits).all()
-        out = (nxt[:, None].astype(jnp.int32), finite, cache)
-        return out + (counts,) if experts else out
+            stats["finite"] = jnp.isfinite(logits).all()
+        return nxt[:, None].astype(jnp.int32), stats, cache
 
     return serve_step
 

@@ -17,8 +17,9 @@ running each costs about 0.4 us (measured on a TPU v5e host). One
   per request placed in a slot (its serve-step calls and host round trips);
 - ``serve.dispatch``: launching the decode step;
 - ``serve.device_get``: the step's one device-to-host transfer (the next
-  tokens, the finiteness flag and, for a model with experts, the expert
-  layers' counts);
+  tokens, the finiteness flag, the cache blocks the decode-attention
+  kernel fetched and, for a model with experts, the expert layers'
+  counts);
 - ``serve.bookkeeping``: the per-slot loop, with ``serve.hooks`` around
   each ``on_token``/``on_finish`` call (the caller's code).
 """
@@ -68,6 +69,8 @@ class EngineStats:
     prefill_tokens: int = 0              # prompt tokens fed through those calls
     experts_touched: int = 0             # decode steps: held experts a live token routed to, summed over layers
     expert_pairs: int = 0                # decode steps: (live token, held expert) pairs, summed over layers
+    kv_blocks_read: int = 0              # decode steps: cache blocks the attention kernel fetched, summed over layers
+    kv_blocks_held: int = 0              # decode steps: cache blocks the caches hold, summed over layers
 
     @property
     def mean_occupancy(self) -> float:
@@ -172,25 +175,27 @@ class ServingEngine:
             return 0
         with TraceAnnotation("serve.dispatch"):
             self._rng, sub = jax.random.split(self._rng)
-            nxt, finite, self._cache, *counts = self._serve(
+            nxt, step_stats, self._cache = self._serve(
                 self.params, self._cache, self._tokens, self._lengths, sub)
             self._tokens = nxt
             self._lengths = self._lengths + 1
         with TraceAnnotation("serve.device_get"):
-            nxt_np, finite, counts = jax.device_get((nxt, finite, counts))
+            nxt_np, step_stats = jax.device_get((nxt, step_stats))
 
         with TraceAnnotation("serve.bookkeeping"):
-            self._bookkeeping(active, nxt_np, finite, counts)
+            self._bookkeeping(active, nxt_np, step_stats)
         return len(active)
 
-    def _bookkeeping(self, active: List[int], nxt_np: np.ndarray, finite: bool,
-                     counts: List[np.ndarray]) -> None:
+    def _bookkeeping(self, active: List[int], nxt_np: np.ndarray,
+                     step_stats: Dict[str, np.ndarray]) -> None:
         self.stats.steps += 1
-        if not finite:
+        if not step_stats["finite"]:
             self.stats.nonfinite_steps += 1
-        if counts:
-            self.stats.experts_touched += int(counts[0][0])
-            self.stats.expert_pairs += int(counts[0][1])
+        self.stats.kv_blocks_read += int(step_stats["kv_blocks"][0])
+        self.stats.kv_blocks_held += int(step_stats["kv_blocks"][1])
+        if "experts" in step_stats:
+            self.stats.experts_touched += int(step_stats["experts"][0])
+            self.stats.expert_pairs += int(step_stats["experts"][1])
         self.stats.batch_occupancy_sum += len(active) / self.n_slots
         for i in active:
             req = self._slots[i]

@@ -9,6 +9,7 @@ pytest.importorskip("hypothesis")  # optional dep: pip install -e .[test]
 from hypothesis import given, settings, strategies as st, HealthCheck
 
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.decode_attention.kernel import block_for, last_filled_block
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.rglru_scan.ops import rglru_scan
 from repro.kernels.wkv6.ops import wkv6
@@ -89,48 +90,115 @@ class TestFlashAttention:
         assert rel_err(out, ref) < 1e-4
 
 
+def _valid_lengths(bk, s):
+    """Valid lengths at each edge of a block: 1, bk - 1, bk, bk + 1, S."""
+    return jnp.asarray([1, bk - 1, bk, bk + 1, s], jnp.int32)
+
+
 class TestDecodeAttention:
-    @given(st.sampled_from([1, 2, 4]), st.sampled_from([1, 2]),
-           st.sampled_from([32, 64]), st.sampled_from([jnp.float32, jnp.bfloat16]))
+    """The kernel fetches and computes only each slot's filled blocks
+    (``kernel.last_filled_block``); at every valid length it equals the masked
+    full read of the reference."""
+
+    @pytest.mark.parametrize("group,kvh", [(1, 1), (2, 2), (3, 8), (8, 4)])
+    @given(st.sampled_from([jnp.float32, jnp.bfloat16]))
     @settings(**SETTINGS)
-    def test_interpret_matches_ref(self, group, kvh, s, dtype):
-        b, d = 2, 16
+    def test_interpret_matches_ref(self, group, kvh, dtype):
+        """The cells' GQA shapes (group 3 of 8 kv heads, 8 of 4) at small S."""
+        s, d, bk = 64, 16, 16
         h = group * kvh
-        key = jax.random.PRNGKey(group * 10 + s)
-        q = jax.random.normal(key, (b, h, d), dtype)
-        k = jax.random.normal(jax.random.fold_in(key, 1), (b, kvh, s, d), dtype)
-        v = jax.random.normal(jax.random.fold_in(key, 2), (b, kvh, s, d), dtype)
-        lengths = jnp.asarray([s // 2, s - 1], jnp.int32)
+        lengths = _valid_lengths(bk, s)
+        key = jax.random.PRNGKey(group * 10 + kvh)
+        q = jax.random.normal(key, (len(lengths), h, d), dtype)
+        k = jax.random.normal(jax.random.fold_in(key, 1), (len(lengths), kvh, s, d), dtype)
+        v = jax.random.normal(jax.random.fold_in(key, 2), (len(lengths), kvh, s, d), dtype)
         ref = decode_attention(q, k, v, lengths, impl="ref")
-        out = decode_attention(q, k, v, lengths, impl="interpret", block_k=16)
+        out = decode_attention(q, k, v, lengths, impl="interpret", block_k=bk)
         tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
         assert rel_err(out, ref) < tol
 
     def test_windowed(self):
+        """A sliding-window layer's ring of W read at ``min(n + 1, W)``, as
+        the decode step reads it, equals the reference over the last W
+        positions of the unrolled sequence: position p lives in slot p % W."""
+        h, kvh, w, d, bk = 4, 2, 32, 16, 16
+        lengths = jnp.asarray([1, 15, 16, 17, 31, 32, 40, 63], jnp.int32)   # valid entries n + 1
+        b = len(lengths)
         key = jax.random.PRNGKey(1)
-        q = jax.random.normal(key, (2, 2, 16))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (2, 2, 64, 16))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (2, 2, 64, 16))
-        lengths = jnp.asarray([40, 63], jnp.int32)
-        ref = decode_attention(q, k, v, lengths, window=16, impl="ref")
-        out = decode_attention(q, k, v, lengths, window=16, impl="interpret", block_k=16)
+        q = jax.random.normal(key, (b, h, d))
+        seq_k = jax.random.normal(jax.random.fold_in(key, 1), (b, kvh, 64, d))
+        seq_v = jax.random.normal(jax.random.fold_in(key, 2), (b, kvh, 64, d))
+        ring_k, ring_v, last_k, last_v = [], [], [], []
+        for i, n in enumerate(np.asarray(lengths)):
+            kept = np.arange(max(n - w, 0), n)
+            slots = np.zeros(w, np.int64)
+            slots[kept % w] = kept
+            ring_k.append(seq_k[i][:, slots])
+            ring_v.append(seq_v[i][:, slots])
+            last = np.arange(w) + max(n - w, 0)
+            last_k.append(seq_k[i][:, last])
+            last_v.append(seq_v[i][:, last])
+        valid = jnp.minimum(lengths, w)
+        ref = decode_attention(q, jnp.stack(last_k), jnp.stack(last_v), valid, impl="ref")
+        out = decode_attention(q, jnp.stack(ring_k), jnp.stack(ring_v), valid, impl="interpret",
+                               block_k=bk)
         assert rel_err(out, ref) < 1e-4
 
     @pytest.mark.parametrize("layer", [0, 2, 3])
     @pytest.mark.parametrize("impl", ["interpret", "ref"])
     def test_layer_index_reads_its_layer_of_the_stack(self, layer, impl):
         """With a layer index into a stacked (L, B, KV, S, d) cache, each
-        path reads that layer as if handed its slice."""
-        n_layers, b, h, kvh, s, d = 4, 2, 4, 2, 64, 16
+        path reads that layer as if handed its slice: a full stack, and a
+        stack of rings of W read at ``min(n + 1, W)`` as the decode step
+        reads them."""
+        n_layers, h, kvh, s, d, bk, w = 4, 4, 2, 64, 16, 16, 32
         key = jax.random.PRNGKey(layer)
+        lengths = _valid_lengths(bk, s)
+        b = len(lengths)
         q = jax.random.normal(key, (b, h, d))
-        k = jax.random.normal(jax.random.fold_in(key, 1), (n_layers, b, kvh, s, d))
-        v = jax.random.normal(jax.random.fold_in(key, 2), (n_layers, b, kvh, s, d))
-        lengths = jnp.asarray([1, s], jnp.int32)
-        ref = decode_attention(q, k[layer], v[layer], lengths, impl="ref")
-        out = jax.jit(lambda l: decode_attention(q, k, v, lengths, l, impl=impl, block_k=16))(
-            jnp.int32(layer))
+        for cache_len, valid in ((s, lengths), (w, jnp.minimum(lengths + 1, w))):
+            k = jax.random.normal(jax.random.fold_in(key, 1), (n_layers, b, kvh, cache_len, d))
+            v = jax.random.normal(jax.random.fold_in(key, 2), (n_layers, b, kvh, cache_len, d))
+            ref = decode_attention(q, k[layer], v[layer], valid, impl="ref")
+            out = jax.jit(lambda l: decode_attention(q, k, v, valid, l, impl=impl,
+                                                     block_k=bk))(jnp.int32(layer))
+            assert rel_err(out, ref) < 1e-4
+
+    @pytest.mark.parametrize("cache_len", [64, 32], ids=["full", "ring"])
+    def test_blocks_outside_the_filled_ones_are_neither_read_nor_computed(self, cache_len):
+        """NaN in every whole block past each slot's last filled block, of a
+        full cache and of a ring read at ``min(n + 1, W)``: the output is
+        finite and equals the reference over the finite cache."""
+        h, kvh, d, bk = 6, 2, 16, 16
+        lengths = jnp.minimum(_valid_lengths(bk, 64), cache_len)
+        b = len(lengths)
+        key = jax.random.PRNGKey(7)
+        q = jax.random.normal(key, (b, h, d))
+        k = jax.random.normal(jax.random.fold_in(key, 1), (b, kvh, cache_len, d))
+        v = jax.random.normal(jax.random.fold_in(key, 2), (b, kvh, cache_len, d))
+        last = last_filled_block(lengths, bk, cache_len // bk)
+        outside = jnp.arange(cache_len)[None] // bk > last[:, None]
+        assert outside.any()
+        nan = outside[:, None, :, None]
+        kn, vn = jnp.where(nan, jnp.nan, k), jnp.where(nan, jnp.nan, v)
+        ref = decode_attention(q, k, v, lengths, impl="ref")
+        out = decode_attention(q, kn, vn, lengths, impl="interpret", block_k=bk)
+        assert np.isfinite(np.asarray(out)).all()
         assert rel_err(out, ref) < 1e-4
+
+    # (kv heads, group, S): phi4-mini, yi-6b, Mellum2's full layers and its
+    # rings, each with the grid steps a call took with a per-head 1024 block
+    @pytest.mark.parametrize("kvh,group,s,old_steps", [
+        (8, 3, 2048, 128), (4, 8, 2048, 64), (4, 8, 2048, 64), (4, 8, 1024, 32)],
+        ids=["phi4-mini", "yi-6b", "mellum2-full", "mellum2-ring"])
+    def test_block_rule_fits_vmem_and_keeps_the_grid(self, kvh, group, s, old_steps):
+        """The block from the shapes at d 128 in bf16: k and v
+        double-buffered fit the default scoped VMEM (16 MiB), it tiles the
+        cache, and a call of 8 slots takes no more grid steps than before."""
+        bk = block_for(s, kvh, 128, 2)
+        assert s % bk == 0 and bk % 8 == 0
+        assert 2 * 2 * kvh * bk * 128 * 2 <= 16 * 1024 * 1024
+        assert 8 * (s // bk) <= old_steps
 
 
 class TestRglruScan:

@@ -197,19 +197,19 @@ def test_serve_path_drops_nothing_when_every_token_routes_to_one_expert():
 
 
 def test_dense_step_returns_what_it_did_and_an_expert_step_adds_its_counts():
-    for arch, n_out in (("yi-6b", 3), (ARCH, 4)):
+    for arch, keys in (("yi-6b", {"finite", "kv_blocks"}), (ARCH, {"finite", "kv_blocks", "experts"})):
         cfg = smoke_config(arch).with_(dtype="float32")
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         cache = model.init_cache(2, 32)
-        out = jax.jit(make_serve_step(model))(params, cache, jnp.zeros((2, 1), jnp.int32),
-                                              jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(1))
-        assert len(out) == n_out
-        nxt, finite, new_cache = out[:3]
-        assert nxt.shape == (2, 1) and nxt.dtype == jnp.int32 and finite.shape == ()
+        nxt, stats, new_cache = jax.jit(make_serve_step(model))(
+            params, cache, jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jax.random.PRNGKey(1))
+        assert nxt.shape == (2, 1) and nxt.dtype == jnp.int32 and stats["finite"].shape == ()
         assert jax.tree.structure(new_cache) == jax.tree.structure(cache)
-        if n_out == 4:
-            assert out[3].shape == (2,) and out[3].dtype == jnp.int32
+        assert set(stats) == keys
+        for k in keys - {"finite"}:
+            assert stats[k].shape == (2,) and stats[k].dtype == jnp.int32
 
 
 def test_engine_counts_the_experts_its_decode_steps_touched():

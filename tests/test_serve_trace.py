@@ -13,6 +13,7 @@ import pytest
 from jax._src.array import ArrayImpl
 
 from repro.configs import smoke_config
+from repro.kernels.decode_attention import kernel as kernel_mod
 from repro.models import build_model
 from repro.models.scopes import DECODE_SCOPES
 from repro.serve import Request, ServingEngine
@@ -131,3 +132,32 @@ def test_compiled_step_is_named_jit_serve_step(model_params):
     for scope in ("embed", "layers", "attn", "kv_cache.update", "mlp", "unembed", "sample"):
         assert f"/{scope}/" in text
     assert DECODE_SCOPES == ("embed", "layers", "attn", "kv_cache.update", "mlp", "unembed", "sample")
+
+
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "mellum2-12b-a2.5b-ep4"], ids=["dense", "window"])
+def test_kv_block_counters_match_a_hand_count(arch, monkeypatch):
+    """``kv_blocks_read`` and ``kv_blocks_held`` over the decode steps, with
+    the kernel's blocks at 8 positions (any block reaches the target).
+
+    Two slots of a 32-position cache, 4 blocks each. Slot 0 holds a prompt
+    of 5 and decodes 6 tokens, at valid lengths 5..10: 1, 1, 1, 1, 2, 2
+    blocks. Slot 1 holds a prompt of 12 and decodes 3, at 12..14: 2 blocks
+    each; then it idles while slot 0 finishes, its stale lengths counting
+    on to 15, 16, 17: 2, 2, 3 blocks. A full layer reads 8 + 6 + 7 = 21 of
+    6 steps x 2 slots x 4 = 48 blocks. A ring of 16 (2 blocks) reads at
+    min(valid, 16): 8 + 6 + (2 + 2 + 2) = 20 of 6 x 2 x 2 = 24 blocks.
+    phi4-mini's smoke model has 2 full layers; Mellum2's 2 full and 6
+    sliding."""
+    monkeypatch.setattr(kernel_mod, "TARGET_BLOCK_BYTES", 1)
+    cfg = smoke_config(arch).with_(dtype="float32")
+    m = build_model(cfg)
+    eng = ServingEngine(m, m.init(jax.random.PRNGKey(0)), n_slots=2, max_len=32)
+    for rid, (p, n) in enumerate(((5, 6), (12, 3))):
+        eng.submit(Request(request_id=rid, prompt=np.arange(1, p + 1, dtype=np.int32),
+                           max_new_tokens=n))
+    stats = eng.run_until_drained()
+    assert stats.steps == 6
+    n_full, n_ring = (2, 0) if arch == "phi4-mini-3.8b" else (2, 6)
+    assert cfg.n_layers == n_full + n_ring
+    assert stats.kv_blocks_read == 21 * n_full + 20 * n_ring
+    assert stats.kv_blocks_held == 48 * n_full + 24 * n_ring

@@ -56,15 +56,21 @@ def _compile(fn, *args, **jit_kwargs):
 
 
 def test_decode_attention_compiles(one_chip):
-    """One layer's cache, and one layer read by index from the stack."""
+    """One layer's cache, and one layer read by index from the stack, at
+    phi4-mini's heads; and stacks at 32 q / 4 kv heads (yi-6b, Mellum2),
+    full and a ring of 1024, each with the block worked out from its
+    shapes."""
     bf = jnp.bfloat16
     q, lengths = _sds((B, H, D), bf, one_chip), _sds((B,), jnp.int32, one_chip)
     layer_cache = _sds((B, KV, MAX_LEN, D), bf, one_chip)
     stacked = _sds((N_LAYERS, B, KV, MAX_LEN, D), bf, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    q32 = _sds((B, 32, D), bf, one_chip)
+    kv4 = [_sds((N_LAYERS, B, 4, s, D), bf, one_chip) for s in (MAX_LEN, 1024)]
     for compiled in (
         _compile(decode_attention_pallas, q, layer_cache, layer_cache, lengths),
-        _compile(decode_attention_pallas, q, stacked, stacked, lengths,
-                 _sds((), jnp.int32, one_chip)),
+        _compile(decode_attention_pallas, q, stacked, stacked, lengths, layer),
+        *(_compile(decode_attention_pallas, q32, c, c, lengths, layer) for c in kv4),
     ):
         text = compiled.as_text()
         assert "tpu_custom_call" in text
